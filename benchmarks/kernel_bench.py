@@ -19,6 +19,7 @@ import json
 import time
 from dataclasses import asdict, dataclass
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -130,7 +131,8 @@ def _exec_modes() -> dict:
     directly and "showed" the fused kernel 14% slower, an artifact of
     interpret-mode emulation, not the kernel)."""
     return {"fused_exec_mode":
-            "pallas_interpret" if ops.INTERPRET else "pallas_compiled",
+            "pallas_interpret" if jax.default_backend() == "cpu"
+            else "pallas_compiled",
             "unfused_exec_mode": "xla"}
 
 

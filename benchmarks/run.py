@@ -13,6 +13,7 @@ import time
 import traceback
 
 from benchmarks.common import emit
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = [
     ("fig1", "benchmarks.fig1_sampling_ratio", "Fig 1a: sampling ratio vs TP"),
@@ -40,6 +41,7 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated prefixes, e.g. fig10,fig13")
     args = ap.parse_args()
+    enable_compile_cache()
     selected = args.only.split(",") if args.only else None
 
     print("name,us_per_call,derived")

@@ -151,6 +151,13 @@ class HostSamplerPool:
     engine's ``baseline`` mode (sampling synchronously on the last stage,
     Eq. 4) and the two paths are bit-identical by construction.
 
+    Placement: ``submit`` commits the fetched logits, the penalty state,
+    the params and every other operand to the host CPU device
+    (``self.device``), so the jitted step runs on the CPU whatever the
+    default device is; the returned state stays there, and the owning
+    engine keeps it there between steps. ``sample_sync`` moves every
+    operand to the logits' own device instead.
+
     ``backend_override`` selects a different registered sampler backend
     for the POOL only (e.g. ``"fused"`` to run the single-pass kernel on
     the host workers while the engine's own plane keeps its configured
@@ -167,6 +174,7 @@ class HostSamplerPool:
                  tracer: Optional[StepTracer] = None):
         self.plane = plane
         self.backend_override = backend_override
+        self.device = jax.devices("cpu")[0]
         self.num_workers = max(1, num_workers)
         # the owning engine's flight recorder (§17): workers record their
         # d2h_transfer / host_sample spans on their own thread tracks —
@@ -207,24 +215,32 @@ class HostSamplerPool:
     # -- worker body ---------------------------------------------------------
     def _fetch(self, logits, lo: int, hi: int):
         """The disaggregation boundary: the shard's logits cross to the
-        host explicitly. Blocks on any in-flight device compute producing
-        them — a separate seam so that wait is timed (and testable) apart
-        from the CPU sampling that follows."""
-        return jnp.asarray(jax.device_get(logits[lo:hi]))
+        host CPU device explicitly. Blocks on any in-flight device compute
+        producing them — a separate seam so that wait is timed (and
+        testable) apart from the CPU sampling that follows."""
+        return jax.device_put(logits[lo:hi], self.device).block_until_ready()
 
     def _run_shard(self, lo: int, hi: int, logits, state, params, bias,
-                   nonces, pos, step, active) -> _ShardResult:
+                   nonces, pos, step, active,
+                   on_host: bool = True) -> _ShardResult:
         t0 = time.perf_counter()
-        shard = self._fetch(logits, lo, hi)
+        shard = self._fetch(logits, lo, hi) if on_host else logits[lo:hi]
         t1 = time.perf_counter()     # sampling clock starts AFTER the fetch
         sl = lambda a: None if a is None else a[lo:hi]
+        # host: commit every operand to the CPU device (a no-op for state
+        # the engine already keeps there); sync: to the logits' device
+        devs = shard.devices()
+        dev = self.device if on_host else \
+            (next(iter(devs)) if len(devs) == 1 else None)
+        put = (lambda x: jax.device_put(x, dev)) if dev is not None \
+            else (lambda x: jax.tree_util.tree_map(jnp.asarray, x))
         tokens, new_state, stats = self._step_jit(
             shard,
-            jax.tree_util.tree_map(sl, state),
-            jax.tree_util.tree_map(sl, params),
-            sl(bias),
-            jnp.asarray(nonces[lo:hi]), jnp.asarray(pos[lo:hi]),
-            jnp.asarray(step, jnp.int32), jnp.asarray(active[lo:hi]))
+            put(jax.tree_util.tree_map(sl, state)),
+            put(jax.tree_util.tree_map(sl, params)),
+            None if bias is None else put(sl(bias)),
+            put(nonces[lo:hi]), put(pos[lo:hi]),
+            put(np.asarray(step, np.int32)), put(active[lo:hi]))
         toks = np.asarray(tokens)        # worker-side host sync
         stats_host = (float(stats.accept_rate), float(stats.alpha_mean),
                       float(stats.fallback_rate))
@@ -273,7 +289,7 @@ class HostSamplerPool:
         result."""
         R = logits.shape[0]
         part = self._run_shard(0, R, logits, state, params, bias, nonces,
-                               pos, step, active)
+                               pos, step, active, on_host=False)
         return PoolResult(tokens=part.tokens, state=part.state,
                           **_pool_stats([part]),
                           sampler_time=part.sampler_time,

@@ -29,11 +29,16 @@ from __future__ import annotations
 
 from typing import List, Optional
 
+import jax
 import numpy as np
 
 from repro.core.decision_plane import DecisionPlane
 from repro.core.host_sampler import HostSamplerPool, PoolResult, SampleTicket
 from repro.obs.tracer import StepTracer
+
+#: sampler backends whose draw is a Pallas kernel: on the CPU such a
+#: kernel only runs in the Pallas interpreter
+PALLAS_BACKENDS = ("fused",)
 
 #: accepted ``sampler_mode`` spellings -> canonical client mode. The
 #: pipeline's original names stay valid so existing configs don't break.
@@ -69,11 +74,19 @@ class DecisionPlaneClient:
     workers draw with that registered backend (e.g. the single-pass
     ``fused`` kernel) while the engine's own plane keeps its configured
     algorithm — the ``--pool-algorithm`` serving knob (DESIGN.md §14).
+
+    On a machine with an accelerator, a pool whose draw is a Pallas kernel
+    is refused (``ValueError``) wherever host placement can happen: at
+    construction in host mode or when ``switchable`` (the adaptive
+    controller may move the decision to the host later), and at
+    :meth:`set_mode`. On the host CPU such a kernel could only run in the
+    Pallas interpreter, far slower than on either side of the link.
     """
 
     def __init__(self, plane: DecisionPlane, mode: str = "device",
                  workers: int = 2, pool_algorithm: Optional[str] = None,
-                 tracer: Optional[StepTracer] = None):
+                 tracer: Optional[StepTracer] = None,
+                 switchable: bool = False):
         self.mode = canonical_sampler_mode(mode)
         self.plane = plane
         # the engine's flight recorder rides through to the pool workers
@@ -81,11 +94,22 @@ class DecisionPlaneClient:
         self.pool = HostSamplerPool(plane, workers,
                                     backend_override=pool_algorithm,
                                     tracer=tracer)
+        if self.is_host or switchable:
+            self._refuse_pallas_on_host()
         self._tickets: List[SampleTicket] = []   # outstanding host work
 
     @property
     def is_host(self) -> bool:
         return self.mode == "host"
+
+    def _refuse_pallas_on_host(self) -> None:
+        algorithm = self.pool.backend_override or self.plane.algorithm
+        if algorithm in PALLAS_BACKENDS and jax.default_backend() != "cpu":
+            raise ValueError(
+                f"sampler backend {algorithm!r} is a Pallas kernel and "
+                "cannot run on the host sampler pool's CPU of a "
+                f"{jax.default_backend()} machine; use a jnp backend for "
+                "the pool (e.g. 'shvs') or keep the decision on the device")
 
     # -- the async surface ---------------------------------------------------
     def submit(self, logits, state, params, bias, nonces: np.ndarray,
@@ -125,6 +149,8 @@ class DecisionPlaneClient:
         mode = canonical_sampler_mode(mode)
         if mode == self.mode:
             return False
+        if mode == "host":
+            self._refuse_pallas_on_host()
         self.drain()
         self.mode = mode
         return True

@@ -134,6 +134,14 @@ def _bucket(n: int, mult: int) -> int:
     return max(mult, ((n + mult - 1) // mult) * mult)
 
 
+def _donates() -> bool:
+    """Whether the step programs donate ``cache``/``pstate``: everywhere
+    but the CPU, whose runtime executes a donating program synchronously
+    on the calling thread, which defeats the async dispatch the
+    overlapped loop is built on. Tests steer it to rehearse donation."""
+    return jax.default_backend() != "cpu"
+
+
 def locked_api(fn):
     """Serialize a public engine method on the instance's ``_api_lock``.
 
@@ -143,12 +151,14 @@ def locked_api(fn):
     reentrant so locked methods may nest (``step`` → ``flush`` on paged
     preemption, ``close`` → ``flush``), and it only serializes the
     host-side orchestration — the device work those calls dispatch stays
-    async underneath."""
+    async underneath. Arrays the call creates land on the engine's
+    ``device`` (the default device for engines without one)."""
     import functools
 
     @functools.wraps(fn)
     def wrapper(self, *args, **kwargs):
-        with self._api_lock:
+        with self._api_lock, \
+                jax.default_device(getattr(self, "device", None)):
             return fn(self, *args, **kwargs)
     return wrapper
 
@@ -360,6 +370,11 @@ class Engine:
         self.ecfg = engine_cfg
         self.model = Model(model_cfg)
         self.params = params
+        # one engine, one device: wherever its parameters were placed
+        # (``launch.serve.build_fleet`` puts replica i on device i); its
+        # cache, decision state and imported payloads go there too
+        self.device = next(iter(
+            jax.tree_util.tree_leaves(params)[0].devices()))
         # chunked prefill is gated to full-causal dense decoders (§8)
         self._chunk_ok = (engine_cfg.prompt_chunk > 0
                           and model_cfg.family in ("dense", "moe")
@@ -429,14 +444,22 @@ class Engine:
             self.decision,
             "device" if self._adaptive else engine_cfg.sampler_mode,
             engine_cfg.samplers, pool_algorithm=engine_cfg.pool_algorithm,
-            tracer=self.tracer)
+            tracer=self.tracer, switchable=self._adaptive)
         self._host = self.client.is_host
         self._metrics.mode_host.set(1.0 if self._host else 0.0)
         self._metrics.pool_workers.set(float(engine_cfg.samplers))
-        self.cache = (init_paged_cache(model_cfg, B, self.pcfg)
-                      if self._paged else self.model.init_cache(B, S))
-        self.pstate = self.decision.init_state(B)
-        self.last_tokens = jnp.zeros((B,), jnp.int32)
+        # committed from the start, as every step program's outputs are:
+        # the first admission then compiles the programs later ones reuse
+        with jax.default_device(self.device):
+            self.cache = jax.device_put(
+                init_paged_cache(model_cfg, B, self.pcfg) if self._paged
+                else self.model.init_cache(B, S), self.device)
+            self.last_tokens = jax.device_put(jnp.zeros((B,), jnp.int32),
+                                              self.device)
+        # host mode keeps the (B, V) penalty histograms on the pool's CPU
+        # device, so they never cross the link on a decode step
+        self.pstate = jax.device_put(self.decision.init_state(B),
+                                     self._state_device())
         self._sp = SlotParams(B, model_cfg.vocab_size)
         # per-slot RNG tags: request nonce + next output position (host-side;
         # activity is decided by the scheduler, so no device sync is needed)
@@ -478,17 +501,23 @@ class Engine:
     def _jit_programs(self) -> None:
         # last_tokens / nonces / pos are never donated — pending commits hold
         # references to token buffers across dispatches (§2). cache/pstate
-        # donation is skipped on CPU: the CPU runtime executes donating
-        # programs synchronously on the calling thread, which defeats the
-        # async dispatch the overlapped loop is built on.
-        donate = () if jax.default_backend() == "cpu" else (1, 2)
-        self._decode_jit = jax.jit(self._decode_impl, donate_argnums=donate)
-        self._chunk_jit = jax.jit(self._chunk_impl, donate_argnums=donate)
+        # are (see _donates): every reader holds the program's outputs, and
+        # host-mode workers are joined before the state they read is
+        # replaced (_resolve_host_pending)
+        donate = _donates()
+        self._decode_jit = jax.jit(self._decode_impl,
+                                   donate_argnums=(1, 2) if donate else ())
+        self._chunk_jit = jax.jit(self._chunk_impl,
+                                  donate_argnums=(1, 2) if donate else ())
         # host sampler mode (§13): forward-only program — the decision
         # plane runs in the client's CPU pool on the fetched logits
-        fwd_donate = () if jax.default_backend() == "cpu" else (1,)
         self._forward_jit = jax.jit(self._forward_impl,
-                                    donate_argnums=fwd_donate)
+                                    donate_argnums=(1,) if donate else ())
+
+    def _state_device(self):
+        """Where ``pstate`` lives: the host pool's CPU device in host mode,
+        the engine's device otherwise."""
+        return self.client.pool.device if self._host else self.device
 
     # -- jitted bodies ---------------------------------------------------------
     def _decode_impl(self, params, cache, pstate, last_tokens, sparams, bias,
@@ -724,7 +753,6 @@ class Engine:
         dispatched = bool(plan.active_slots.any())
         if dispatched:
             active = jnp.asarray(plan.active_slots)
-            sparams = self._sp.as_params()
             if self._host:
                 # §13: dispatch the forward-only program (async) and hand
                 # the logits FUTURE to the sampler pool — the workers, not
@@ -733,8 +761,11 @@ class Engine:
                 t_disp = time.perf_counter()
                 logits, self.cache = self._forward_jit(
                     self.params, self.cache, self.last_tokens, active)
+                # the pool's copy of the contract rows stays on its CPU
+                cpu = self.client.pool.device
                 ticket = self.client.submit(
-                    logits, self.pstate, sparams, self._sp.bias_array(),
+                    logits, self.pstate, self._sp.as_params(cpu),
+                    self._sp.bias_array(cpu),
                     self._nonce.copy(), self._pos.copy(), plan.step,
                     plan.active_slots.copy())
                 self._pending.append(_Pending(
@@ -749,7 +780,7 @@ class Engine:
                 t_disp = time.perf_counter()
                 tokens, self.cache, self.pstate, stats = self._decode_jit(
                     self.params, self.cache, self.pstate, self.last_tokens,
-                    sparams, self._sp.bias_array(),
+                    self._sp.as_params(), self._sp.bias_array(),
                     jnp.asarray(self._nonce.copy()),
                     jnp.asarray(self._pos.copy()),
                     jnp.asarray(plan.step, jnp.int32), active)
@@ -819,7 +850,7 @@ class Engine:
         if lock is None:           # __init__ died before the first stmt
             self._closed = True
             return
-        with lock:
+        with lock, jax.default_device(getattr(self, "device", None)):
             if self._closed:
                 return
             self._closed = True
@@ -1036,7 +1067,7 @@ class Engine:
                     self.tracer.add("pool_stall", t0, t1,
                                     name=f"stall@step{ent.step}",
                                     step=ent.step)
-                self.last_tokens = jnp.asarray(ent.res.tokens)
+                self.last_tokens = jax.device_put(ent.res.tokens, self.device)
                 self.pstate = ent.res.state
 
     def _drain_one(self) -> Optional[StepRecord]:
@@ -1054,7 +1085,7 @@ class Engine:
                     self.tracer.add("pool_stall", t0, t1,
                                     name=f"stall@step{ent.step}",
                                     step=ent.step)
-                self.last_tokens = jnp.asarray(ent.res.tokens)
+                self.last_tokens = jax.device_put(ent.res.tokens, self.device)
                 self.pstate = ent.res.state
             toks_np = ent.res.tokens
         else:
@@ -1138,6 +1169,9 @@ class Engine:
         self._resolve_host_pending()
         self.client.set_mode(mode)
         self._host = self.client.is_host
+        # the histograms follow the placement: a jitted step cannot mix
+        # arrays committed to two devices
+        self.pstate = jax.device_put(self.pstate, self._state_device())
         self._metrics.mode_host.set(1.0 if self._host else 0.0)
         return True
 
@@ -1198,6 +1232,7 @@ class Engine:
                                     request_id=int(r.request_id))
         first, rows_cache, rows_pstate, lens, bases, rids = \
             prefill_new_rows(self, new_requests, self.scheduler.step)
+        rows_pstate = jax.device_put(rows_pstate, self._state_device())
         slots = jnp.asarray([r.slot for r in new_requests], jnp.int32)
         # insert rows into batch state (device-side, chains off any
         # still-running decode through the donated cache/pstate futures)
@@ -1334,12 +1369,16 @@ class Engine:
             if task.final:
                 finish[task.slot] = True
                 finishers.append((task.slot, task.request))
-        first, self.last_tokens, self.cache, self.pstate = self._chunk_jit(
-            self.params, self.cache, self.pstate, jnp.asarray(toks),
+        # the chunk program samples finishers' first tokens on the device;
+        # in host mode the histograms visit it and go back to the CPU
+        first, self.last_tokens, self.cache, pstate = self._chunk_jit(
+            self.params, self.cache,
+            jax.device_put(self.pstate, self.device), jnp.asarray(toks),
             jnp.asarray(counts), jnp.asarray(mask), jnp.asarray(finish),
             self._sp.as_params(), self._sp.bias_array(),
             jnp.asarray(self._nonce.copy()),
             self.last_tokens, jnp.asarray(self.scheduler.step, jnp.int32))
+        self.pstate = jax.device_put(pstate, self._state_device())
         if self._paged:
             for task in chunks:
                 self._slot_len[task.slot] += task.end - task.start
@@ -1400,8 +1439,10 @@ class SlotParams:
         # operand so the jitted program signature stops flip-flopping
         # (zero rows are exact no-ops on the logits).
         self._bias_dense: Optional[np.ndarray] = None
-        self._cached: Optional[SamplingParams] = None
-        self._bias_cached: Optional[jnp.ndarray] = None
+        # device structs, one per device they were asked for (None = the
+        # default device): host mode keeps its copy on the pool's CPU
+        self._cached: Dict[object, SamplingParams] = {}
+        self._bias_cached: Dict[object, jnp.ndarray] = {}
 
     def set_row(self, i: int, cfg: SamplingConfig) -> None:
         self.temperature[i] = cfg.effective_temperature
@@ -1421,38 +1462,43 @@ class SlotParams:
             for t, b in cfg.logit_bias:
                 if 0 <= t < self.vocab_size:
                     self._bias_dense[i, t] += b
-            self._bias_cached = None
-        self._cached = None
+            self._bias_cached = {}
+        self._cached = {}
 
     def reset_row(self, i: int) -> None:
         """Return row ``i`` to the default contract when its slot frees
         (retire/preempt) so nothing stale survives into the next occupant."""
         self.set_row(i, SamplingConfig())
 
-    def as_params(self) -> SamplingParams:
-        if self._cached is None:
+    def as_params(self, device=None) -> SamplingParams:
+        """The rows as device arrays on ``device`` (default device if
+        None), rebuilt only after a row changed."""
+        if device not in self._cached:
             # .copy(): the device structs may alias host numpy buffers
             # zero-copy; set_row mutations must never reach a program that
             # is already in flight (or silently change the cached struct)
-            self._cached = SamplingParams(
-                temperature=jnp.asarray(self.temperature.copy()),
-                top_k=jnp.asarray(self.top_k.copy()),
-                top_p=jnp.asarray(self.top_p.copy()),
-                min_p=jnp.asarray(self.min_p.copy()),
-                repetition_penalty=jnp.asarray(self.repetition.copy()),
-                presence_penalty=jnp.asarray(self.presence.copy()),
-                frequency_penalty=jnp.asarray(self.frequency.copy()),
-                seed=jnp.asarray(self.seed.copy()),
-                use_seed=jnp.asarray(self.use_seed.copy()),
+            put = lambda x: jax.device_put(x.copy(), device)
+            self._cached[device] = SamplingParams(
+                temperature=put(self.temperature),
+                top_k=put(self.top_k),
+                top_p=put(self.top_p),
+                min_p=put(self.min_p),
+                repetition_penalty=put(self.repetition),
+                presence_penalty=put(self.presence),
+                frequency_penalty=put(self.frequency),
+                seed=put(self.seed),
+                use_seed=put(self.use_seed),
             )
-        return self._cached
+        return self._cached[device]
 
-    def bias_array(self) -> Optional[jnp.ndarray]:
-        """Dense (B, V) logit-bias operand, or None while no request has
-        ever used logit_bias (the jitted programs then skip the add)."""
+    def bias_array(self, device=None) -> Optional[jnp.ndarray]:
+        """Dense (B, V) logit-bias operand on ``device``, or None while no
+        request has ever used logit_bias (the jitted programs then skip
+        the add)."""
         if self._bias_dense is None:
             return None
-        if self._bias_cached is None:
+        if device not in self._bias_cached:
             # .copy() for the same aliasing reason as as_params()
-            self._bias_cached = jnp.asarray(self._bias_dense.copy())
-        return self._bias_cached
+            self._bias_cached[device] = jax.device_put(
+                self._bias_dense.copy(), device)
+        return self._bias_cached[device]

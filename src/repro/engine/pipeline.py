@@ -759,6 +759,9 @@ class PipelineEngine:
                           "len": rows_cache["len"], "pos": rows_cache["pos"]}
                 self.caches[s][i] = _insert_rows(self.caches[s][i], rows_s,
                                                  slots_j)
+        # the group's state may sit on the host pool's CPU device
+        rows_pstate = jax.device_put(
+            rows_pstate, next(iter(self.pstate[i].prompt_counts.devices())))
         self.pstate[i] = pen.PenaltyState(
             prompt_counts=self.pstate[i].prompt_counts.at[slots_j].set(
                 rows_pstate.prompt_counts),

@@ -230,6 +230,10 @@ class Replica:
 
     # -- worker body --------------------------------------------------------
     def _finish(self, h: _Handle, err: Optional[BaseException]) -> None:
+        # counted before on_done tells the client the stream is over, so a
+        # stats read that follows the stream already sees it
+        with self._lock:
+            self._served += 1
         if h.work.on_done is not None:
             try:
                 h.work.on_done(h.work.request, err)
@@ -237,7 +241,6 @@ class Replica:
                 pass                      # a sink bug must not kill the loop
         with self._lock:
             self._load -= 1
-            self._served += 1
             if self._load == 0:
                 self._drained.set()
 

@@ -26,6 +26,7 @@ from repro.gateway.client import stream_completion
 from repro.gateway.codec import ByteCodec
 from repro.gateway.fleet import ReplicaFleet
 from repro.gateway.http import GatewayServer
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 
 VOCAB = 512        # > ByteCodec.vocab_limit (257) so text prompts fit
@@ -131,6 +132,7 @@ def main(argv=None) -> int:
                     help="split the fleet into prefill/decode roles with "
                          "paged-KV migration (DESIGN.md §18)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     ref = reference_streams(args.max_new)
     wire = asyncio.run(wire_streams(args.replicas, args.max_new,

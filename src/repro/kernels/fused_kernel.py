@@ -25,12 +25,16 @@ but needs no second pass for the normalizer.
 All tile math is shared verbatim with ``ref.fused_sample_ref`` (the
 tile-faithful oracle), so kernel and oracle are bit-identical, including
 float accumulation order. Grid: (B/block_b, V/block_v), vocab innermost
-(sequential on TPU), accumulating into revisited output blocks.
+(sequential on TPU), accumulating into revisited output blocks. Per-row
+operands and outputs travel as (B, 1) columns: Mosaic tiles a rank-1
+block only by 128 or by the whole array, and a column block of
+``block_b`` rows tiles for any B.
 
-NOTE on compiled mode: the buffer merge sorts (block_b, K + block_v) values
-per tile (``jnp.argsort``); interpret mode (this container's default)
-executes it as plain jax ops. A Mosaic-compiled build would lower it to a
-bitonic merge — same semantics, kept out of scope here.
+Mosaic compiles the kernel on a TPU (``ops`` decides by the platform the
+call is lowered for; ``tests/test_tpu_compile.py`` compiles it for a
+described v5e). The shared helpers use only what Mosaic lowers: the
+top-K merge is K rounds of max-extraction instead of a sort, the prefix
+sum a triangular matmul, and the u32 <-> f32 casts go through int32.
 """
 from __future__ import annotations
 
@@ -59,11 +63,11 @@ def _fused_kernel(rep_ref, pres_ref, freq_ref, temp_ref, tk_ref, tp_ref,
     cp = cp_ref[...]
     co = co_ref[...]
     seen = ((cp > 0) | (co > 0)).astype(jnp.float32)
-    f = 1.0 + (rep_ref[...][:, None] - 1.0) * seen
+    f = 1.0 + (rep_ref[...] - 1.0) * seen           # rows are (bb, 1)
     z = jnp.where(z > 0, z / f, z * f)
-    z = z - pres_ref[...][:, None] * (co > 0).astype(jnp.float32)
-    z = z - freq_ref[...][:, None] * co.astype(jnp.float32)
-    zs = z / jnp.maximum(temp_ref[...][:, None], 1e-6)
+    z = z - pres_ref[...] * (co > 0).astype(jnp.float32)
+    z = z - freq_ref[...] * co.astype(jnp.float32)
+    zs = z / jnp.maximum(temp_ref[...], 1e-6)
 
     @pl.when(j == 0)
     def _init():
@@ -80,19 +84,26 @@ def _fused_kernel(rep_ref, pres_ref, freq_ref, temp_ref, tk_ref, tp_ref,
     m_ref[...] = m
     stot_ref[...] = s_tot
     shot_ref[...] = s_hot
-    bb = zs.shape[0]
-    tile_idx = jax.lax.broadcasted_iota(jnp.int32, (bb, block_v), 1) \
-        + j * block_v
-    vals, idx = topk_merge(vals_ref[...], idx_ref[...], zs, tile_idx)
-    vals_ref[...] = vals
-    idx_ref[...] = idx
+    bb, K = vals_ref.shape
+    # a tile whose every value is <= its row's K-th buffered value cannot
+    # enter the buffer (ties keep the lower, buffered id), so its merge
+    # would return the buffer unchanged: skip it
+    enters = jnp.max(zs, axis=-1, keepdims=True) > vals_ref[:, K - 1:]
+
+    @pl.when(jnp.max(enters.astype(jnp.int32)) > 0)
+    def _merge():
+        tile_idx = jax.lax.broadcasted_iota(jnp.int32, (bb, block_v), 1) \
+            + j * block_v
+        vals, idx = topk_merge(vals_ref[...], idx_ref[...], zs, tile_idx)
+        vals_ref[...] = vals
+        idx_ref[...] = idx
 
     # -- final vocab tile: filter + draw on the (bb, K) buffer --------------
     @pl.when(j == nv - 1)
     def _epilogue():
         tokens, exact, kept = trunc_gumbel_draw(
-            vals, idx, s_tot, tk_ref[...], tp_ref[...], mp_ref[...],
-            temp_ref[...], _u32_from_uniform(u_ref[...]))
+            vals_ref[...], idx_ref[...], s_tot, tk_ref[...], tp_ref[...],
+            mp_ref[...], temp_ref[...], _u32_from_uniform(u_ref[...]))
         tok_ref[...] = tokens
         exact_ref[...] = exact.astype(jnp.int32)
         alpha_ref[...] = s_hot / jnp.maximum(s_tot, 1e-30)
@@ -118,12 +129,13 @@ def fused_sample(z, counts_p, counts_o, repetition, presence, frequency,
     grid = (B // block_b, V // block_v)
     tile = lambda: pl.BlockSpec((block_b, block_v), lambda i, j: (i, j),
                                 memory_space=pltpu.VMEM)
-    row = lambda: pl.BlockSpec((block_b,), lambda i, j: (i,),
+    row = lambda: pl.BlockSpec((block_b, 1), lambda i, j: (i, 0),
                                memory_space=pltpu.VMEM)
     buf = lambda: pl.BlockSpec((block_b, K), lambda i, j: (i, 0),
                                memory_space=pltpu.VMEM)
     kernel = functools.partial(_fused_kernel, block_v=block_v,
                                vocab_padded=V)
+    col = lambda x, dt: jnp.asarray(x, dt).reshape(B, 1)
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -132,21 +144,21 @@ def fused_sample(z, counts_p, counts_o, repetition, presence, frequency,
                                              memory_space=pltpu.VMEM)],
         out_specs=[row(), row(), row(), row(), buf(), buf(), row(), row(),
                    row()],
-        out_shape=[jax.ShapeDtypeStruct((B,), jnp.int32),
-                   jax.ShapeDtypeStruct((B,), jnp.int32),
-                   jax.ShapeDtypeStruct((B,), jnp.float32),
-                   jax.ShapeDtypeStruct((B,), jnp.int32),
+        out_shape=[jax.ShapeDtypeStruct((B, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((B, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((B, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((B, 1), jnp.int32),
                    jax.ShapeDtypeStruct((B, K), jnp.float32),
                    jax.ShapeDtypeStruct((B, K), jnp.int32),
-                   jax.ShapeDtypeStruct((B,), jnp.float32),
-                   jax.ShapeDtypeStruct((B,), jnp.float32),
-                   jax.ShapeDtypeStruct((B,), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((B, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((B, 1), jnp.float32)],
         interpret=interpret,
-    )(repetition.astype(jnp.float32), presence.astype(jnp.float32),
-      frequency.astype(jnp.float32), temperature.astype(jnp.float32),
-      jnp.asarray(top_k, jnp.int32), top_p.astype(jnp.float32),
-      min_p.astype(jnp.float32), u_row.astype(jnp.float32),
+    )(col(repetition, jnp.float32), col(presence, jnp.float32),
+      col(frequency, jnp.float32), col(temperature, jnp.float32),
+      col(top_k, jnp.int32), col(top_p, jnp.float32),
+      col(min_p, jnp.float32), col(u_row, jnp.float32),
       z, jnp.asarray(counts_p, jnp.int32), jnp.asarray(counts_o, jnp.int32),
       jnp.asarray(hot_mask, jnp.int32))
-    tokens, exact, alpha, kept = out[0], out[1], out[2], out[3]
+    tokens, exact, alpha, kept = (o[:, 0] for o in out[:4])
     return tokens, exact, alpha, kept

@@ -1,13 +1,13 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Handles padding to block multiples, dtype coercion, and the
-interpret-vs-compiled switch (interpret=True executes the kernel body in
-Python on CPU — the validation mode used in this container; on a real TPU
-set ``REPRO_PALLAS_INTERPRET=0``).
+interpret-vs-compiled choice, which follows the platform a call is lowered
+for (:func:`_on_platform`): compiled by Mosaic on a TPU, run by the Pallas
+interpreter on the CPU, where there is no Mosaic.
 """
 from __future__ import annotations
 
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -16,8 +16,17 @@ from repro.kernels import penalty_kernel, shvs_kernel, gumbel_kernel
 from repro.kernels import fused_kernel
 from repro.kernels import ref  # noqa: F401  (re-exported for convenience)
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
 NEG_INF = -1e30
+
+
+def _on_platform(kernel, *args, **static):
+    """Call a Pallas ``kernel`` interpreted where the enclosing program is
+    lowered for the CPU and compiled everywhere else. The choice is made
+    per lowering, so one traced program is right on either backend."""
+    return jax.lax.platform_dependent(
+        *args,
+        cpu=functools.partial(kernel, interpret=True, **static),
+        default=functools.partial(kernel, interpret=False, **static))
 
 
 def _pad_axis(x, axis: int, mult: int, value=0):
@@ -46,9 +55,9 @@ def fused_penalty_scale(logits, counts_p, counts_o, repetition, presence,
     pres, _ = _pad_axis(presence.astype(jnp.float32), 0, bb)
     freq, _ = _pad_axis(frequency.astype(jnp.float32), 0, bb)
     temp, _ = _pad_axis(temperature.astype(jnp.float32), 0, bb, 1.0)
-    out = penalty_kernel.penalty_scale(
-        zb, cpb, cob, rep, pres, freq, temp,
-        block_b=bb, block_v=min(block_v, zb.shape[1]), interpret=INTERPRET)
+    out = _on_platform(
+        penalty_kernel.penalty_scale, zb, cpb, cob, rep, pres, freq, temp,
+        block_b=bb, block_v=min(block_v, zb.shape[1]))
     return out[:B, :V]
 
 
@@ -61,9 +70,9 @@ def fused_shvs_masses(z, hot_mask, *, block_b: int = 8, block_v: int = 512):
     # padded columns: hot & NEG_INF => contribute exp(-inf)=0 to s_hot and
     # never touch tail_max
     zp, _ = _pad_axis(zp, 0, bb, NEG_INF)
-    m, s_hot, s_tail, tmax = shvs_kernel.shvs_masses(
-        zp, hm, block_b=bb, block_v=min(block_v, zp.shape[1]),
-        interpret=INTERPRET)
+    m, s_hot, s_tail, tmax = _on_platform(
+        shvs_kernel.shvs_masses, zp, hm, block_b=bb,
+        block_v=min(block_v, zp.shape[1]))
     return m[:B], s_hot[:B], s_tail[:B], tmax[:B]
 
 
@@ -84,9 +93,9 @@ def fused_sample(logits, counts_p, counts_o, params, u_row, hot_mask, *,
         params.temperature, params.top_k, params.top_p, params.min_p,
         u_row, hot_mask, block_b=block_b, block_v=block_v)
     z = padded[0]
-    tokens, exact, alpha, kept = fused_kernel.fused_sample(
-        *padded, k_cap=min(k_cap, z.shape[1]), block_b=bb,
-        block_v=min(block_v, z.shape[1]), interpret=INTERPRET)
+    tokens, exact, alpha, kept = _on_platform(
+        fused_kernel.fused_sample, *padded, k_cap=min(k_cap, z.shape[1]),
+        block_b=bb, block_v=min(block_v, z.shape[1]))
     return (jnp.minimum(tokens[:B], V - 1), exact[:B] != 0, alpha[:B],
             kept[:B])
 
@@ -97,7 +106,7 @@ def fused_gumbel_argmax(z, seed, *, block_b: int = 8, block_v: int = 512):
     bb = min(block_b, B) if B % min(block_b, B) == 0 else 1
     zp, _ = _pad_axis(z.astype(jnp.float32), 1, block_v, NEG_INF)
     zp, _ = _pad_axis(zp, 0, bb, NEG_INF)
-    toks = gumbel_kernel.gumbel_argmax(
-        zp, seed, block_b=bb, block_v=min(block_v, zp.shape[1]),
-        interpret=INTERPRET)
+    toks = _on_platform(
+        gumbel_kernel.gumbel_argmax, zp, seed, block_b=bb,
+        block_v=min(block_v, zp.shape[1]))
     return jnp.minimum(toks[:B], V - 1)

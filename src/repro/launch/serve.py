@@ -73,6 +73,7 @@ from repro.config import ARCH_IDS, SamplingConfig, SHVSConfig, get_arch
 from repro.core.sampler_backend import registered_backends
 from repro.engine import Engine, PipelineConfig, PipelineEngine, Request
 from repro.engine.engine import EngineConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.obs import StepTracer, Telemetry, write_chrome_trace
 
@@ -83,12 +84,16 @@ def build_engine(arch: str, reduced: bool, algorithm: str, batch: int,
                  block_size: int = 16, num_blocks: int = 0,
                  stages: int = 1, microbatches: int = 0, samplers: int = 2,
                  sampler_mode: str = None, pool_algorithm: str = None,
-                 telemetry: Telemetry = None):
+                 telemetry: Telemetry = None, device=None):
+    """``device``: where the engine lives (its parameters are made and
+    committed there, and the engine follows them); default device if
+    None."""
     cfg = get_arch(arch)
     if reduced:
         cfg = cfg.reduced()
     model = Model(cfg)
-    params = model.init(jax.random.PRNGKey(seed))
+    with jax.default_device(device):
+        params = jax.device_put(model.init(jax.random.PRNGKey(seed)), device)
     common = dict(max_batch=batch, max_seq_len=max_seq,
                   algorithm=algorithm,
                   shvs=SHVSConfig(hot_size=min(1024, cfg.vocab_size // 4)),
@@ -146,7 +151,9 @@ def synth_requests(n: int, vocab: int, max_new: int, rng_seed: int = 0,
 def build_fleet(args):
     """N identically-parameterized replicas (same model seed → the same
     weights, so seeded streams match across replicas) wrapped in a
-    :class:`~repro.gateway.fleet.ReplicaFleet`.
+    :class:`~repro.gateway.fleet.ReplicaFleet`. Replica *i* lives on
+    ``jax.devices()[i % n]``: on a four-chip host, four replicas hold
+    four chips.
 
     With ``--disaggregate`` the fleet is P prefill-role + D decode-role
     replicas (DESIGN.md §18): ``GatewayServer`` builds its router via
@@ -166,6 +173,7 @@ def build_fleet(args):
         n_decode = args.decode_replicas or max(1, args.replicas - n_prefill)
         roles = ["prefill"] * n_prefill + ["decode"] * n_decode
     n = len(roles) if roles else args.replicas
+    devices = jax.devices()
     engines = [
         build_engine(args.arch, args.reduced, args.algorithm, args.batch,
                      args.max_seq, overlap=args.overlap,
@@ -174,8 +182,9 @@ def build_fleet(args):
                      stages=args.stages, microbatches=args.microbatches,
                      samplers=args.samplers, sampler_mode=args.sampler_mode,
                      pool_algorithm=args.pool_algorithm,
-                     telemetry=_trace_telemetry(args.trace_out))
-        for _ in range(n)]
+                     telemetry=_trace_telemetry(args.trace_out),
+                     device=devices[i % len(devices)])
+        for i in range(n)]
     return ReplicaFleet(engines, capacity=args.capacity, roles=roles)
 
 
@@ -264,7 +273,9 @@ def run_disaggregated_batch(args) -> None:
               f"finish_reason={r.finish_reason}")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
+    """This module's command line (also parsed by ``chip_smoke.py`` so
+    its fleets are built from the flags a user would pass)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ARCH_IDS), default="smollm-360m")
     ap.add_argument("--reduced", action="store_true",
@@ -357,7 +368,12 @@ def main() -> None:
                          "engines' step spans, the pool workers' "
                          "fetch/sample spans, and (gateway mode) the "
                          "wire-level request spans")
-    args = ap.parse_args()
+    return ap
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    enable_compile_cache()
 
     if args.gateway:
         run_gateway(args)

@@ -92,16 +92,7 @@ def model_spec_entry():
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` with replication checking disabled.
-
-    jax >= 0.6 exposes ``jax.shard_map`` (keyword ``check_vma``); older
-    releases only have ``jax.experimental.shard_map.shard_map`` (keyword
-    ``check_rep``). All call sites in this repo disable the check because
-    outputs mix per-shard and replicated values.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """``jax.shard_map`` with the varying-manual-axes check off: every call
+    site in this repo returns a mix of per-shard and replicated values."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -323,3 +323,26 @@ def test_adaptive_engine_exposes_controller(model):
     eng2 = Engine(cfg, params, EngineConfig(**_ENGINE_KW))
     assert eng2._dpc is None
     eng2.close()
+
+
+@adaptive
+def test_adaptive_switches_with_donated_buffers(model, monkeypatch):
+    """The accelerator's donation branch, rehearsed on the CPU: with
+    ``cache``/``pstate`` donated, placement switches in both directions
+    move the histograms between the pool's CPU device and the engine's
+    and read no donated buffer — the streams stay the device-mode ones."""
+    from repro.engine import engine as engine_mod
+    cfg, params = model
+    ref, _ = _streams(cfg, params, "device")
+
+    def force(eng):
+        eng._dpc.adjust_every = 2
+        eng._dpc.dwell = 2
+        eng._dpc.queue_high = -1.0
+        eng._dpc.queue_low = 99.0
+
+    monkeypatch.setattr(engine_mod, "_donates", lambda: True)
+    got, log = _streams(cfg, params, "adaptive", tweak=force)
+    switched = [r["sampler_mode"] for r in log if "sampler_mode" in r]
+    assert "host" in switched and "device" in switched, switched
+    assert got == ref

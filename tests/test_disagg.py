@@ -429,3 +429,14 @@ def test_disagg_wire_identity_over_http():
                                     disaggregate=True))
     for p in PROMPTS:
         assert wire[p] == ref[p], f"stream for {p!r} diverged"
+
+
+def test_migration_identity_with_donated_buffers(monkeypatch):
+    """Export and import read the live cache and histograms after the
+    step programs donated their inputs (the accelerator branch, steered
+    on here): the migrated streams stay the unmigrated ones."""
+    from repro.engine import engine as engine_mod
+    ref = _run_single("paged", True, True)
+    monkeypatch.setattr(engine_mod, "_donates", lambda: True)
+    assert _run_single("paged", True, True) == ref
+    assert _run_migrated("paged", "contiguous", True, True) == ref

@@ -130,3 +130,31 @@ def test_heterogeneous_sampling_params(small_model):
     eng.submit(reqs)
     done = eng.run(max_steps=50)
     assert len(done) == 3 and all(len(r.output) == 4 for r in done)
+
+
+@pytest.mark.parametrize("mode", [
+    dict(),
+    dict(overlap=False),
+    dict(sampler_mode="host"),
+    dict(cache="paged", prompt_chunk=8),
+    dict(sampler_mode="host", prompt_chunk=8),
+], ids=["device", "sequential", "host", "paged-chunked", "host-chunked"])
+def test_donated_buffers_are_never_read(small_model, monkeypatch, mode):
+    """Rehearse the accelerator's donation branch on the CPU (whose
+    runtime honours donation too): with ``cache``/``pstate`` donated, a
+    reader of a donated buffer raises "Array has been deleted", and the
+    streams must equal the non-donating run's."""
+    from repro.engine import engine as engine_mod
+    cfg, params = small_model
+
+    def run():
+        eng = _engine(cfg, params, **mode)
+        eng.submit(_reqs(6, cfg.vocab_size, max_new=6, seed=1,
+                         temperature=0.9, top_k=20, repetition_penalty=1.1))
+        out = {r.request_id: list(r.output) for r in eng.run(max_steps=200)}
+        eng.close()
+        return out
+
+    want = run()
+    monkeypatch.setattr(engine_mod, "_donates", lambda: True)
+    assert run() == want

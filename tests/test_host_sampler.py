@@ -24,9 +24,10 @@ from repro.core.host_sampler import (HostSamplerPool, _pool_stats,
 from repro.core.sampling import SamplingParams
 
 
-def _pool(V=64, workers=2, algorithm="reference"):
+def _pool(V=64, workers=2, algorithm="reference", backend_override=None):
     return HostSamplerPool(DecisionPlane(V, algorithm=algorithm, k_cap=32,
-                                         seed=0), workers)
+                                         seed=0), workers,
+                           backend_override=backend_override)
 
 
 def _inputs(B=8, V=64, active=None, seed=0):
@@ -145,3 +146,43 @@ def test_refresh_rejits_worker_program():
         assert pool._step_jit is not before
     finally:
         pool.close()
+
+
+class TestPlacement:
+    def test_pool_step_runs_on_a_cpu_device(self):
+        """``submit`` commits every operand to the host CPU device, so the
+        jitted step — and the state it returns — live there whatever the
+        default device is; ``sample_sync`` stays with the logits."""
+        pool = _pool(workers=2)
+        try:
+            assert pool.device.platform == "cpu"
+            res = pool.submit(*_inputs()).result()
+            sync = pool.sample_sync(*_inputs())
+        finally:
+            pool.close()
+        for leaf in (res.state.prompt_counts, res.state.output_counts):
+            assert leaf.devices() == {pool.device}
+            assert leaf.committed
+        assert sync.state.output_counts.devices() == \
+            _inputs()[0].devices()
+
+    def test_pallas_backend_refused_on_an_accelerator_host(self,
+                                                           monkeypatch):
+        """On a machine with an accelerator, a Pallas kernel placed on the
+        pool's CPU would only run interpreted: refused at construction
+        wherever host placement can happen, and at a switch to it."""
+        import jax
+        from repro.engine.decision_client import DecisionPlaneClient
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        shvs = DecisionPlane(64, algorithm="shvs", k_cap=32, seed=0)
+        with pytest.raises(ValueError, match="Pallas kernel"):
+            DecisionPlaneClient(shvs, "host", pool_algorithm="fused")
+        plane = DecisionPlane(64, algorithm="fused", k_cap=32, seed=0)
+        for mode, switchable in (("host", False), ("device", True)):
+            with pytest.raises(ValueError, match="Pallas kernel"):
+                DecisionPlaneClient(plane, mode, switchable=switchable)
+        client = DecisionPlaneClient(plane, "device")   # device: fine
+        with pytest.raises(ValueError, match="Pallas kernel"):
+            client.set_mode("host")
+        assert client.mode == "device"
+        client.close()

@@ -349,3 +349,47 @@ class TestRegisteredBackendIdentity:
             logits[keep], sub_state, sub_params, 0,
             rng_tags=(jnp.asarray(nonces[keep]), jnp.asarray(pos[keep])))[0])
         np.testing.assert_array_equal(sub, full[keep])
+
+
+class TestMosaicLowerableHelpers:
+    """The shared helpers were rewritten in forms Mosaic lowers; each must
+    give the bits its direct jnp form gives, so streams do not move."""
+
+    def test_u32_to_f32_matches_direct_cast(self):
+        edge = np.array([0, 1, 2**16 - 1, 2**16, 2**24 - 1, 2**24,
+                         2**24 + 1, 2**24 + 3, 2**31 - 1, 2**31,
+                         2**32 - 129, 2**32 - 128, 2**32 - 127, 2**32 - 1],
+                        np.uint64).astype(np.uint32)
+        rnd = np.random.default_rng(0).integers(
+            0, 2**32, 200_000, dtype=np.uint64).astype(np.uint32)
+        x = jnp.asarray(np.concatenate([edge, rnd]))
+        got = np.asarray(ref._u32_to_f32(x)).view(np.uint32)
+        want = np.asarray(x.astype(jnp.float32)).view(np.uint32)
+        np.testing.assert_array_equal(got, want)
+
+    @pytest.mark.parametrize("seed", [0, 12345, ref.FUSED_DRAW_SALT])
+    def test_hash_uniform_gives_the_same_bits_as_before(self, seed):
+        def direct(seed, b, v):        # the form before the int32 halves
+            x = (b.astype(jnp.uint32) * jnp.uint32(2654435761) ^
+                 v.astype(jnp.uint32) * jnp.uint32(40503) ^
+                 jnp.uint32(seed))
+            x = x ^ (x >> jnp.uint32(16))
+            x = x * jnp.uint32(2246822519)
+            x = x ^ (x >> jnp.uint32(13))
+            x = x * jnp.uint32(3266489917)
+            x = x ^ (x >> jnp.uint32(16))
+            return (x.astype(jnp.float32) + 0.5) * (1.0 / 4294967296.0)
+
+        b = jax.lax.broadcasted_iota(jnp.int32, (64, 4096), 0)
+        v = jax.lax.broadcasted_iota(jnp.int32, (64, 4096), 1) * 37
+        np.testing.assert_array_equal(
+            np.asarray(ref._hash_uniform(seed, b, v)).view(np.uint32),
+            np.asarray(direct(seed, b, v)).view(np.uint32))
+
+    def test_u32_from_uniform_matches_direct_cast(self):
+        u = np.random.default_rng(1).random(100_000).astype(np.float32)
+        u = jnp.asarray(np.concatenate(
+            [u, np.float32([0.0, 0.5, 1.0 - 2**-24])]))
+        np.testing.assert_array_equal(
+            np.asarray(ref._u32_from_uniform(u)),
+            np.asarray((u * 16777216.0).astype(jnp.uint32)))

@@ -425,3 +425,47 @@ def test_sizing_model_hstar_is_argmin(data):
     grid = np.arange(8, V, 64)
     f_min = model.expected_cost(grid).min()
     assert model.expected_cost(h_star) <= f_min * 1.02
+
+
+@pytest.mark.kernels
+@given(st.data())
+@settings(max_examples=40, deadline=None)
+def test_topk_merge_equals_stable_sort_merge(data):
+    """The max-extraction merge ≡ concatenating buffer and tile and taking
+    a stable descending sort's first K — including exact ties (values
+    drawn from a small set) and the fresh buffer's ``-inf`` padding."""
+    import jax.numpy as jnp
+    from repro.kernels import ref
+
+    bb = data.draw(st.integers(1, 4))
+    K = data.draw(st.sampled_from([1, 3, 8, 16]))
+    bv = data.draw(st.sampled_from([4, 8, 32]))
+    earlier = data.draw(st.integers(0, 2 * K))
+    seed = data.draw(st.integers(0, 2**31 - 1))
+    rng = np.random.default_rng(seed)
+    pool = np.float32([-np.inf, -1.0, 0.0, 0.5, 2.0])
+    sentinel = earlier + bv               # the kernel's padded-vocab id
+
+    def stable_top(vals, ids):
+        order = np.argsort(-vals, axis=-1, kind="stable")[:, :K]
+        return (np.take_along_axis(vals, order, -1),
+                np.take_along_axis(ids, order, -1))
+
+    # the running buffer: a previous merge of ``earlier`` lower ids into
+    # the -inf / sentinel initial buffer
+    init_v = np.full((bb, K), -np.inf, np.float32)
+    init_i = np.full((bb, K), sentinel, np.int32)
+    prev_v = rng.choice(pool, (bb, earlier)).astype(np.float32)
+    prev_i = np.broadcast_to(np.arange(earlier, dtype=np.int32),
+                             (bb, earlier))
+    vals, idx = stable_top(np.concatenate([init_v, prev_v], -1),
+                           np.concatenate([init_i, prev_i], -1))
+    tile_v = rng.choice(pool, (bb, bv)).astype(np.float32)
+    tile_i = np.broadcast_to(earlier + np.arange(bv, dtype=np.int32),
+                             (bb, bv))
+    want_v, want_i = stable_top(np.concatenate([vals, tile_v], -1),
+                                np.concatenate([idx, tile_i], -1))
+    got_v, got_i = ref.topk_merge(jnp.asarray(vals), jnp.asarray(idx),
+                                  jnp.asarray(tile_v), jnp.asarray(tile_i))
+    np.testing.assert_array_equal(np.asarray(got_v), want_v)
+    np.testing.assert_array_equal(np.asarray(got_i), want_i)
