@@ -30,7 +30,6 @@ accounted separately (``transfer_time`` vs ``sampler_time``).
 """
 from __future__ import annotations
 
-import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import List, NamedTuple, Optional
 
@@ -223,39 +222,41 @@ class HostSamplerPool:
     def _run_shard(self, lo: int, hi: int, logits, state, params, bias,
                    nonces, pos, step, active,
                    on_host: bool = True) -> _ShardResult:
-        t0 = time.perf_counter()
-        shard = self._fetch(logits, lo, hi) if on_host else logits[lo:hi]
-        t1 = time.perf_counter()     # sampling clock starts AFTER the fetch
-        sl = lambda a: None if a is None else a[lo:hi]
-        # host: commit every operand to the CPU device (a no-op for state
-        # the engine already keeps there); sync: to the logits' device
-        devs = shard.devices()
-        dev = self.device if on_host else \
-            (next(iter(devs)) if len(devs) == 1 else None)
-        put = (lambda x: jax.device_put(x, dev)) if dev is not None \
-            else (lambda x: jax.tree_util.tree_map(jnp.asarray, x))
-        tokens, new_state, stats = self._step_jit(
-            shard,
-            put(jax.tree_util.tree_map(sl, state)),
-            put(jax.tree_util.tree_map(sl, params)),
-            None if bias is None else put(sl(bias)),
-            put(nonces[lo:hi]), put(pos[lo:hi]),
-            put(np.asarray(step, np.int32)), put(active[lo:hi]))
-        toks = np.asarray(tokens)        # worker-side host sync
-        stats_host = (float(stats.accept_rate), float(stats.alpha_mean),
-                      float(stats.fallback_rate))
-        t2 = time.perf_counter()
-        if self.tracer.enabled:
-            # same stamps as the returned decomposition: the trace and the
-            # stats stream can never disagree about where the time went
-            self.tracer.add("d2h_transfer", t0, t1,
-                            name=f"fetch[{lo}:{hi}]", step=int(step))
-            self.tracer.add("host_sample", t1, t2,
-                            name=f"sample[{lo}:{hi}]", step=int(step))
+        tr = self.tracer
+        # phases on this worker's thread: the profiler trace shows them
+        # beside the device, and the ring (when enabled) records the very
+        # stamps of the returned decomposition, so the trace and the
+        # stats stream can never disagree about where the time went
+        with tr.phase("d2h_transfer", name=f"fetch[{lo}:{hi}]",
+                      step=int(step)) as fetch:
+            shard = self._fetch(logits, lo, hi) if on_host \
+                else logits[lo:hi]
+        # the sampling clock starts AFTER the fetch
+        with tr.phase("host_sample", name=f"sample[{lo}:{hi}]",
+                      step=int(step)) as sample:
+            sl = lambda a: None if a is None else a[lo:hi]
+            # host: commit every operand to the CPU device (a no-op for
+            # state the engine already keeps there); sync: to the logits'
+            # device
+            devs = shard.devices()
+            dev = self.device if on_host else \
+                (next(iter(devs)) if len(devs) == 1 else None)
+            put = (lambda x: jax.device_put(x, dev)) if dev is not None \
+                else (lambda x: jax.tree_util.tree_map(jnp.asarray, x))
+            tokens, new_state, stats = self._step_jit(
+                shard,
+                put(jax.tree_util.tree_map(sl, state)),
+                put(jax.tree_util.tree_map(sl, params)),
+                None if bias is None else put(sl(bias)),
+                put(nonces[lo:hi]), put(pos[lo:hi]),
+                put(np.asarray(step, np.int32)), put(active[lo:hi]))
+            toks = np.asarray(tokens)        # worker-side host sync
+            stats_host = (float(stats.accept_rate), float(stats.alpha_mean),
+                          float(stats.fallback_rate))
         return _ShardResult(tokens=toks, state=new_state, stats=stats_host,
                             active_rows=int(np.count_nonzero(active[lo:hi])),
-                            transfer_time=t1 - t0,
-                            sampler_time=t2 - t1)
+                            transfer_time=fetch.t1 - fetch.t0,
+                            sampler_time=sample.t1 - sample.t0)
 
     # -- client surface ------------------------------------------------------
     def submit(self, logits, state: pen.PenaltyState, params, bias,

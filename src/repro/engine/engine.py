@@ -266,6 +266,16 @@ def generate_stream(eng, requests: List[Request], max_steps: int = 10_000):
             f"unfinished: {open_ids}")
 
 
+def admission_shape(eng, new_requests: List[Request]):
+    """Each admitted row's context (prompt, or prompt+output for a resumed
+    row) and the group's padded length ``Sp`` (bucketed, capped at the
+    cache)."""
+    ctxs = [r.context_tokens() if r.output else r.prompt
+            for r in new_requests]
+    Sp = _bucket(max(len(c) for c in ctxs), eng.ecfg.prompt_bucket)
+    return ctxs, min(Sp, eng.ecfg.max_seq_len)
+
+
 def prefill_new_rows(eng, new_requests: List[Request], step_idx: int):
     """Shared admission math behind :meth:`Engine._admit` and
     :meth:`PipelineEngine._admit_group` — one implementation so the
@@ -274,17 +284,14 @@ def prefill_new_rows(eng, new_requests: List[Request], step_idx: int):
     ``(P, Sp)``), rebuild resumed rows' prompt/output histogram split
     (presence/frequency penalties read C_o — Eq. 5), and sample each row's
     first token at its resume position. ``eng`` needs ``cfg`` / ``ecfg`` /
-    ``params`` / ``decision`` / ``_prefill_cache`` / ``_prefill_impl``.
+    ``params`` / ``decision`` / ``tracer`` / ``_prefill_cache`` /
+    ``_prefill_impl``.
 
     Returns ``(first, rows_cache, rows_pstate, lens, bases, rids)`` —
     ``first`` is the (P,) device token array; the caller owns the install
     into its batch/stage state."""
     P = len(new_requests)
-    ctxs = [r.context_tokens() if r.output else r.prompt
-            for r in new_requests]
-    maxlen = max(len(c) for c in ctxs)
-    Sp = _bucket(maxlen, eng.ecfg.prompt_bucket)
-    Sp = min(Sp, eng.ecfg.max_seq_len)
+    ctxs, Sp = admission_shape(eng, new_requests)
     toks = np.zeros((P, Sp), np.int32)
     lens = np.zeros((P,), np.int32)
     bases = np.zeros((P,), np.int32)   # next output position per row
@@ -316,11 +323,12 @@ def prefill_new_rows(eng, new_requests: List[Request], step_idx: int):
     sp_rows = SlotParams(P, V)
     for i, r in enumerate(new_requests):
         sp_rows.set_row(i, r.sampling)
-    first, rows_pstate, _ = eng.decision.step(
-        logits, rows_pstate, sp_rows.as_params(),
-        jnp.asarray(step_idx, jnp.int32),
-        rng_tags=(jnp.asarray(rids), jnp.asarray(bases)),
-        logit_bias=sp_rows.bias_array())
+    with eng.tracer.phase("admit_decide", rows=P):
+        first, rows_pstate, _ = eng.decision.step(
+            logits, rows_pstate, sp_rows.as_params(),
+            jnp.asarray(step_idx, jnp.int32),
+            rng_tags=(jnp.asarray(rids), jnp.asarray(bases)),
+            logit_bias=sp_rows.bias_array())
     return first, rows_cache, rows_pstate, lens, bases, rids
 
 
@@ -523,14 +531,19 @@ class Engine:
     def _decode_impl(self, params, cache, pstate, last_tokens, sparams, bias,
                      nonces, pos, step, active):
         lens0 = cache["len"]
-        logits, cache = self.model.decode_step(params, last_tokens, cache)
+        # the scopes name the program's two halves in its ops' metadata
+        # (``op_name``), so a profiler trace splits the step between them
+        with jax.named_scope("forward"):
+            logits, cache = self.model.decode_step(params, last_tokens,
+                                                   cache)
         # inactive rows (mid-prefill / retired-but-uncommitted slots) must
         # not advance their cache write offset
         cache = dict(cache)
         cache["len"] = jnp.where(active, lens0 + 1, lens0)
-        tokens, pstate, stats = self.decision.step(
-            logits, pstate, sparams, step, active=active,
-            rng_tags=(nonces, pos), logit_bias=bias)
+        with jax.named_scope("decision"):
+            tokens, pstate, stats = self.decision.step(
+                logits, pstate, sparams, step, active=active,
+                rng_tags=(nonces, pos), logit_bias=bias)
         tokens = jnp.where(active, tokens, 0)
         return tokens, cache, pstate, stats
 
@@ -558,12 +571,14 @@ class Engine:
                     sparams, bias, nonces, last_tokens, step):
         """One prompt chunk for every mid-prefill row; rows finishing their
         prompt sample their first token (position 0) in the same program."""
-        logits, cache = self.model.prefill_chunk(params, toks, cache,
-                                                 counts, mask)
-        tokens, pstate, _ = self.decision.step(
-            logits, pstate, sparams, step, active=finish,
-            rng_tags=(nonces, jnp.zeros_like(nonces, jnp.int32)),
-            logit_bias=bias)
+        with jax.named_scope("forward"):
+            logits, cache = self.model.prefill_chunk(params, toks, cache,
+                                                     counts, mask)
+        with jax.named_scope("decision"):
+            tokens, pstate, _ = self.decision.step(
+                logits, pstate, sparams, step, active=finish,
+                rng_tags=(nonces, jnp.zeros_like(nonces, jnp.int32)),
+                logit_bias=bias)
         tokens = jnp.where(finish, tokens, 0)
         last_tokens = jnp.where(finish, tokens, last_tokens)
         return tokens, last_tokens, cache, pstate
@@ -724,7 +739,11 @@ class Engine:
         # depend on wall-clock timing, which shifts admission *grouping*
         # (different (P, Sp) prefill programs → bitwise logit drift) and
         # breaks run-to-run determinism. The drain point is fixed instead.
-        plan = self.scheduler.schedule()
+        # Each synchronous stretch is a tracer phase (DESIGN.md §17), so a
+        # profiler trace attributes the device's idle time to it.
+        tr = self.tracer
+        with tr.phase("schedule"):
+            plan = self.scheduler.schedule()
         if self._host:
             # install the in-flight ticket's tokens + penalty state BEFORE
             # admission/chunks overwrite their slots' rows: the CPU workers
@@ -737,9 +756,26 @@ class Engine:
         if plan.new_requests:
             self._admit(plan.new_requests)
         if plan.new_chunked:
-            self._admit_chunked(plan.new_chunked)
+            with tr.phase("prefill", name=f"chunked x{len(plan.new_chunked)}",
+                          rows=len(plan.new_chunked)):
+                self._admit_chunked(plan.new_chunked)
         if plan.chunks:
-            self._run_chunks(plan.chunks)
+            with tr.phase("chunk", rows=len(plan.chunks)):
+                self._run_chunks(plan.chunks)
+        with tr.phase("dispatch", step=plan.step):
+            dispatched = self._dispatch(plan)
+        # drain: sequential mode syncs everything now; overlapped mode keeps
+        # exactly one decode in flight so the device never waits on the host
+        keep = 1 if (self.ecfg.overlap and dispatched) else 0
+        rec: Optional[StepRecord] = None
+        while len(self._pending) > keep:
+            rec = self._drain_one() or rec
+        return rec if rec is not None else {}
+
+    def _dispatch(self, plan) -> bool:
+        """Dispatch the step's decode (device mode) or forward-only
+        program plus its sampler-pool ticket (host mode) for every active
+        row. Returns whether anything was dispatched."""
         # refresh decode activity: a prompt's first token may already satisfy
         # the stop condition; chunk finishers join the decode batch
         plan.active_slots = np.array(
@@ -793,13 +829,7 @@ class Engine:
             self._pos += plan.active_slots
             if self._paged:
                 self._slot_len += plan.active_slots
-        # drain: sequential mode syncs everything now; overlapped mode keeps
-        # exactly one decode in flight so the device never waits on the host
-        keep = 1 if (self.ecfg.overlap and dispatched) else 0
-        rec: Optional[StepRecord] = None
-        while len(self._pending) > keep:
-            rec = self._drain_one() or rec
-        return rec if rec is not None else {}
+        return dispatched
 
     @locked_api
     def flush(self) -> None:
@@ -1059,50 +1089,48 @@ class Engine:
         The scheduler-side commit still happens at the drain point."""
         for ent in self._pending:
             if ent.kind == "host" and ent.res is None:
-                t0 = time.perf_counter()
-                ent.res = ent.ticket.result()
-                t1 = time.perf_counter()
-                ent.stall = t1 - t0
-                if self.tracer.enabled:
-                    self.tracer.add("pool_stall", t0, t1,
-                                    name=f"stall@step{ent.step}",
-                                    step=ent.step)
-                self.last_tokens = jax.device_put(ent.res.tokens, self.device)
-                self.pstate = ent.res.state
+                self._resolve_ticket(ent)
+
+    def _resolve_ticket(self, ent: _Pending) -> None:
+        """Block on one host ticket (the measured pool stall) and install
+        its tokens and penalty state into engine state."""
+        with self.tracer.phase("pool_stall", name=f"stall@step{ent.step}",
+                               step=ent.step) as ph:
+            ent.res = ent.ticket.result()
+        ent.stall = ph.t1 - ph.t0
+        self.last_tokens = jax.device_put(ent.res.tokens, self.device)
+        self.pstate = ent.res.state
 
     def _drain_one(self) -> Optional[StepRecord]:
         """Fetch the oldest pending result to the host and commit it. This
         is the only place engine iterations block on the device (device
         mode) or the sampler pool (host mode, if not already resolved)."""
         ent = self._pending.pop(0)
-        if ent.kind == "host":
-            if ent.res is None:       # sequential mode drains immediately
-                t0 = time.perf_counter()
-                ent.res = ent.ticket.result()
-                t1 = time.perf_counter()
-                ent.stall = t1 - t0
-                if self.tracer.enabled:
-                    self.tracer.add("pool_stall", t0, t1,
-                                    name=f"stall@step{ent.step}",
-                                    step=ent.step)
-                self.last_tokens = jax.device_put(ent.res.tokens, self.device)
-                self.pstate = ent.res.state
-            toks_np = ent.res.tokens
-        else:
-            toks_np = np.asarray(ent.tokens)      # host sync point
+        with self.tracer.phase("drain", step=ent.step):
+            if ent.kind == "host":
+                if ent.res is None:   # sequential mode drains immediately
+                    self._resolve_ticket(ent)
+                toks_np = ent.res.tokens
+            else:
+                toks_np = np.asarray(ent.tokens)      # host sync point
         now = time.perf_counter()
         if ent.kind == "decode" and self.tracer.enabled:
             # dispatch -> host materialization of the fused decode program
             self.tracer.add("forward", ent.t_dispatch, now,
                             name=f"decode@step{ent.step}", step=ent.step)
+        with self.tracer.phase("commit", name=f"commit@step{ent.step}",
+                               step=ent.step):
+            return self._commit(ent, toks_np, now)
+
+    def _commit(self, ent: _Pending, toks_np: np.ndarray,
+                now: float) -> Optional[StepRecord]:
+        """Commit one drained result to request state, then fold its
+        record into the controller(s), the metrics and ``stats_log``."""
         if ent.kind == "first":
             for slot, req in ent.finishers:
                 req.record_token(int(toks_np[slot]), now)
             return None
         self.scheduler.commit(toks_np, ent.slot_request, ent.active, now=now)
-        if self.tracer.enabled:
-            self.tracer.add("commit", now, time.perf_counter(),
-                            name=f"commit@step{ent.step}", step=ent.step)
         # queue state is stamped on EVERY record (§17): the controller,
         # /metrics, and the benchmarks consume one validated stream
         common = dict(step=ent.step, batch=int(ent.active.sum()),
@@ -1215,44 +1243,55 @@ class Engine:
         the prefill entirely: its KV, penalty state, and RNG position are
         installed bitwise into the assigned slot."""
         carried = [r for r in new_requests if r.kv_payload is not None]
-        if carried:
-            self._install_imports(carried)
-            cids = {id(r) for r in carried}
-            new_requests = [r for r in new_requests if id(r) not in cids]
-            if not new_requests:
-                return
-        t_pf = time.perf_counter()
-        if self.tracer.enabled:
+        fresh = [r for r in new_requests if r.kv_payload is None]
+        shape = {}
+        if fresh:
+            ctxs, Sp = admission_shape(self, fresh)
+            shape = dict(padded=Sp, tokens=sum(len(c) for c in ctxs))
+        with self.tracer.phase("prefill", name=f"prefill x{len(fresh)}",
+                               rows=len(fresh), **shape):
+            if carried:
+                self._install_imports(carried)
+            if fresh:
+                self._prefill_rows(fresh)
+
+    def _prefill_rows(self, new_requests: List[Request]) -> None:
+        """The prefill half of :meth:`_admit`: run the group's prefill and
+        first-token decision, insert the rows into the batch state, and
+        commit each row's first token once it is on the host."""
+        tr = self.tracer
+        if tr.enabled:
             # arrival -> admission wait per request (0-stamped offline
             # traces carry no arrival clock; skip those)
+            t_pf = time.perf_counter()
             for r in new_requests:
                 if r.arrival_time:
-                    self.tracer.add("queue_wait", r.arrival_time, t_pf,
-                                    name=f"wait#{r.request_id}",
-                                    request_id=int(r.request_id))
+                    tr.add("queue_wait", r.arrival_time, t_pf,
+                           name=f"wait#{r.request_id}",
+                           request_id=int(r.request_id))
         first, rows_cache, rows_pstate, lens, bases, rids = \
             prefill_new_rows(self, new_requests, self.scheduler.step)
-        rows_pstate = jax.device_put(rows_pstate, self._state_device())
-        slots = jnp.asarray([r.slot for r in new_requests], jnp.int32)
         # insert rows into batch state (device-side, chains off any
         # still-running decode through the donated cache/pstate futures)
-        if self._paged:
-            self._paged_insert(new_requests, rows_cache, lens)
-        else:
-            self.cache = _insert_rows(self.cache, rows_cache, slots)
-        self.pstate = pen.PenaltyState(
-            prompt_counts=self.pstate.prompt_counts.at[slots].set(
-                rows_pstate.prompt_counts),
-            output_counts=self.pstate.output_counts.at[slots].set(
-                rows_pstate.output_counts),
-        )
-        self.last_tokens = self.last_tokens.at[slots].set(first)
+        with tr.phase("admit_insert", rows=len(new_requests)):
+            rows_pstate = jax.device_put(rows_pstate, self._state_device())
+            slots = jnp.asarray([r.slot for r in new_requests], jnp.int32)
+            if self._paged:
+                self._paged_insert(new_requests, rows_cache, lens)
+            else:
+                self.cache = _insert_rows(self.cache, rows_cache, slots)
+            self.pstate = pen.PenaltyState(
+                prompt_counts=self.pstate.prompt_counts.at[slots].set(
+                    rows_pstate.prompt_counts),
+                output_counts=self.pstate.output_counts.at[slots].set(
+                    rows_pstate.output_counts),
+            )
+            self.last_tokens = self.last_tokens.at[slots].set(first)
+        with tr.phase("admit_fetch", rows=len(new_requests)):
+            first_np = np.asarray(first)   # blocks on the prefill program
+        # stamped once the first tokens are on the host, as a client sees
+        # them (Request.first_token_time, token_times[0])
         now = time.perf_counter()
-        first_np = np.asarray(first)   # blocks on the prefill program only
-        if self.tracer.enabled:
-            self.tracer.add("prefill", t_pf, time.perf_counter(),
-                            name=f"prefill x{len(new_requests)}",
-                            rows=len(new_requests))
         for i, r in enumerate(new_requests):
             self._sp.set_row(r.slot, r.sampling)
             self._nonce[r.slot] = rids[i]

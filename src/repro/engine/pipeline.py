@@ -64,8 +64,8 @@ from repro.core.decision_plane import DecisionPlane
 from repro.core.host_sampler import PoolResult, SampleTicket
 from repro.engine.decision_client import DecisionPlaneClient
 from repro.engine.engine import (EngineConfig, SlotParams, _insert_rows,
-                                 generate_stream, locked_api,
-                                 prefill_new_rows)
+                                 admission_shape, generate_stream,
+                                 locked_api, prefill_new_rows)
 from repro.engine.paged_cache import (BlockAllocator, PagedCacheConfig,
                                       init_paged_cache)
 from repro.engine.request import Request, RequestState
@@ -570,22 +570,18 @@ class PipelineEngine:
         if active is None:
             active = rec.active
         inputs = jnp.asarray(self.last_tokens[i]) if s == 0 else mb.x
-        t0 = time.perf_counter()
-        out, cache = self._stage_jits[s](
-            self.stage_params[s], inputs, self._stage_cache(s, i),
-            jnp.asarray(active))
-        out.block_until_ready()          # honest per-stage busy time
-        t1 = time.perf_counter()
-        busy = t1 - t0
+        # one timeline row per stage: overlap between stage rows and the
+        # pool workers' host_sample rows is the paper's Eq. 4 win
+        with self.tracer.phase("stage", name=f"s{s}/mb{i}",
+                               track=f"stage{s}", microbatch=i, stage=s,
+                               cycle=self.planner.cycle) as ph:
+            out, cache = self._stage_jits[s](
+                self.stage_params[s], inputs, self._stage_cache(s, i),
+                jnp.asarray(active))
+            out.block_until_ready()      # honest per-stage busy time
         self._store_stage_cache(s, i, dict(cache))
         if self._cycle_rec is not None:
-            self._cycle_rec.busy[s] = busy
-        if self.tracer.enabled:
-            # one timeline row per stage: overlap between stage rows and
-            # the pool workers' host_sample rows is the paper's Eq. 4 win
-            self.tracer.add("stage", t0, t1, name=f"s{s}/mb{i}",
-                            track=f"stage{s}", microbatch=i, stage=s,
-                            cycle=self.planner.cycle)
+            self._cycle_rec.busy[s] = ph.t1 - ph.t0
         if s == self.p - 1:
             mb.x = None
             mb.stage_next = 0
@@ -605,20 +601,17 @@ class PipelineEngine:
                 rec.nonces, rec.positions, rec.exit_cycle,
                 rec.active)
         if not self.client.is_host:
-            t0 = time.perf_counter()
-            mb.ready = self.client.sample_sync(*args)
-            t1 = time.perf_counter()
-            dt = t1 - t0
+            # Eq. 4 baseline: the draw sits ON the last stage's row, right
+            # where it blocks the cycle
+            with self.tracer.phase("host_sample", name=f"sync-sample/mb{i}",
+                                   track=f"stage{self.p - 1}",
+                                   microbatch=i) as ph:
+                mb.ready = self.client.sample_sync(*args)
+            dt = ph.t1 - ph.t0
             if self._cycle_rec is not None:
                 self._cycle_rec.sample = dt
                 if self._cycle_rec.busy[self.p - 1] is not None:
                     self._cycle_rec.busy[self.p - 1] += dt
-            if self.tracer.enabled:
-                # Eq. 4 baseline: the draw sits ON the last stage's row,
-                # right where it blocks the cycle
-                self.tracer.add("host_sample", t0, t1,
-                                name=f"sync-sample/mb{i}",
-                                track=f"stage{self.p - 1}", microbatch=i)
         else:
             mb.ticket = self.client.submit(*args)
 
@@ -631,26 +624,27 @@ class PipelineEngine:
             res, mb.ready = mb.ready, None
             stall = 0.0
         else:
-            t0 = time.perf_counter()
-            res = mb.ticket.result()
-            t1 = time.perf_counter()
-            stall = t1 - t0
+            with self.tracer.phase("pool_stall", name=f"stall/mb{i}",
+                                   microbatch=i,
+                                   cycle=self.planner.cycle) as ph:
+                res = mb.ticket.result()
+            stall = ph.t1 - ph.t0
             mb.ticket = None
-            if self.tracer.enabled:
-                self.tracer.add("pool_stall", t0, t1,
-                                name=f"stall/mb{i}", microbatch=i,
-                                cycle=self.planner.cycle)
         if self._cycle_rec is not None:
             self._cycle_rec.stall = stall
             self._cycle_rec.sampler = res.sampler_time
             self._cycle_rec.transfer = res.transfer_time
+        with self.tracer.phase("commit", name=f"commit/mb{i}", microbatch=i,
+                               cycle=self.planner.cycle):
+            return self._commit_result(i, rec, res, stall)
+
+    def _commit_result(self, i: int, rec: _Dispatch, res,
+                       stall: float) -> StepRecord:
+        """Commit microbatch ``i``'s drawn tokens to request state and its
+        record to the stats stream, the controller and the metrics."""
         now = time.perf_counter()
         self.scheduler.commit(res.tokens, rec.slot_request, rec.active,
                               now=now)
-        if self.tracer.enabled:
-            self.tracer.add("commit", now, time.perf_counter(),
-                            name=f"commit/mb{i}", microbatch=i,
-                            cycle=self.planner.cycle)
         self.pstate[i] = res.state
         self.last_tokens[i] = np.where(rec.active, res.tokens, 0).astype(
             np.int32)
@@ -735,16 +729,45 @@ class PipelineEngine:
         with :meth:`Engine._admit` (``engine.prefill_new_rows``), so the
         engines' bit-identity cannot drift; only the install targets one
         slot group here."""
-        t_pf = time.perf_counter()
-        if self.tracer.enabled:
+        ctxs, Sp = admission_shape(self, new_requests)
+        with self.tracer.phase("prefill",
+                               name=f"prefill x{len(new_requests)}/mb{i}",
+                               rows=len(new_requests), padded=Sp,
+                               tokens=sum(len(c) for c in ctxs),
+                               microbatch=i):
+            self._prefill_group(i, new_requests)
+
+    def _prefill_group(self, i: int, new_requests: List[Request]) -> None:
+        tr = self.tracer
+        if tr.enabled:
+            t_pf = time.perf_counter()
             for r in new_requests:
                 if r.arrival_time:
-                    self.tracer.add("queue_wait", r.arrival_time, t_pf,
-                                    name=f"wait#{r.request_id}",
-                                    request_id=int(r.request_id),
-                                    microbatch=i)
+                    tr.add("queue_wait", r.arrival_time, t_pf,
+                           name=f"wait#{r.request_id}",
+                           request_id=int(r.request_id), microbatch=i)
         first, rows_cache, rows_pstate, lens, bases, rids = \
             prefill_new_rows(self, new_requests, self.planner.cycle)
+        with tr.phase("admit_insert", rows=len(new_requests)):
+            self._insert_group(i, new_requests, rows_cache, rows_pstate,
+                               lens)
+        with tr.phase("admit_fetch", rows=len(new_requests)):
+            first_np = np.asarray(first)
+        # stamped once the first tokens are on the host (Engine._admit)
+        now = time.perf_counter()
+        base_slot = i * self.R
+        for k, r in enumerate(new_requests):
+            local = r.slot - base_slot
+            self._sp[i].set_row(local, r.sampling)
+            self._nonce[i][local] = rids[k]
+            self._pos[i][local] = int(bases[k]) + 1
+            self.last_tokens[i][local] = int(first_np[k])
+            r.record_token(int(first_np[k]), now)
+
+    def _insert_group(self, i: int, new_requests: List[Request], rows_cache,
+                      rows_pstate, lens: np.ndarray) -> None:
+        """Install freshly prefilled rows into microbatch ``i``'s per-stage
+        caches and penalty state."""
         base_slot = i * self.R
         locals_ = np.asarray([r.slot - base_slot for r in new_requests],
                              np.int32)
@@ -767,19 +790,6 @@ class PipelineEngine:
                 rows_pstate.prompt_counts),
             output_counts=self.pstate[i].output_counts.at[slots_j].set(
                 rows_pstate.output_counts))
-        now = time.perf_counter()
-        first_np = np.asarray(first)
-        if self.tracer.enabled:
-            self.tracer.add("prefill", t_pf, time.perf_counter(),
-                            name=f"prefill x{len(new_requests)}/mb{i}",
-                            rows=len(new_requests), microbatch=i)
-        for k, r in enumerate(new_requests):
-            local = int(locals_[k])
-            self._sp[i].set_row(local, r.sampling)
-            self._nonce[i][local] = rids[k]
-            self._pos[i][local] = int(bases[k]) + 1
-            self.last_tokens[i][local] = int(first_np[k])
-            r.record_token(int(first_np[k]), now)
 
     def _paged_insert_group(self, i: int, new_requests: List[Request],
                             rows_cache, lens: np.ndarray,

@@ -11,12 +11,11 @@ from repro.obs.metrics import (DEFAULT_MS_BUCKETS, Counter, Gauge,
 from repro.obs.records import CycleRecord, RecordMapping, StepRecord
 from repro.obs.telemetry import EngineMetrics, Telemetry
 from repro.obs.tracer import (NULL_SPAN, NULL_TRACER, SPAN_KINDS,
-                              SpanEvent, StepTracer, merge_events)
+                              SpanEvent, StepTracer)
 
 __all__ = [
     "StepRecord", "CycleRecord", "RecordMapping",
     "StepTracer", "SpanEvent", "SPAN_KINDS", "NULL_TRACER", "NULL_SPAN",
-    "merge_events",
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "render_registries", "DEFAULT_MS_BUCKETS",
     "chrome_trace", "chrome_trace_events", "write_chrome_trace",

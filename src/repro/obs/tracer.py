@@ -22,6 +22,13 @@ argument is made of, one kind per seam —
     ``commit``        scheduler.commit of a step's tokens
     ``queue_wait``    a request's arrival → admission wait
     ``decision``      a controller action (instant event, §15)
+    ``schedule``      ``scheduler.schedule()`` at the top of an engine step
+    ``admit_decide``  the admission's first-token ``DecisionPlane.step``
+    ``admit_insert``  scattering admitted rows into the batch state
+    ``admit_fetch``   the host blocking on the admitted rows' first tokens
+    ``chunk``         one prompt-chunk program for the mid-prefill rows
+    ``dispatch``      building a step's operands and dispatching its program
+    ``drain``         the host blocking on the oldest in-flight result
     ``request``       one request's wire-level life on the gateway
     ``kv_migrate``    one migration's export gather or import scatter
                       (prefill/decode disaggregation, §18)
@@ -36,6 +43,15 @@ Chrome-trace export — overlap between the pool workers' ``host_sample``
 spans and the engine track's next ``forward``/``stage`` span is the
 paper's Eq. 4 claim, made visually inspectable.
 
+Two sinks (:meth:`StepTracer.phase`): a *synchronous* phase — work the
+calling thread does from entry to exit — always enters a
+``jax.profiler.TraceAnnotation`` named ``obs.<kind>``, so a profiler trace
+shows it on the host line beside the device's ops, and is also recorded
+into the ring when the tracer is enabled. *Asynchronous* spans
+(``forward``: dispatch to host materialization, across steps;
+``queue_wait``: arrival to admission) have no thread that holds them open
+and stay ring-only, recorded after the fact with :meth:`StepTracer.add`.
+
 Overhead discipline: a disabled tracer's :meth:`StepTracer.span` returns
 one shared no-op context manager (no allocation) and ``add``/``instant``
 return immediately; instrumentation sites that build f-string names
@@ -47,7 +63,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from typing import Callable, Iterable, List, NamedTuple, Optional, Tuple
+from typing import Callable, List, NamedTuple, Optional, Tuple
 
 #: the typed span taxonomy (DESIGN.md §17) — unknown kinds are rejected
 #: at record time so a typo'd instrumentation site fails loudly in tests,
@@ -55,8 +71,21 @@ from typing import Callable, Iterable, List, NamedTuple, Optional, Tuple
 SPAN_KINDS = frozenset({
     "prefill", "forward", "stage", "d2h_transfer", "host_sample",
     "pool_stall", "commit", "queue_wait", "decision", "request",
-    "kv_migrate", "handoff_wait",
+    "kv_migrate", "handoff_wait", "schedule", "admit_decide",
+    "admit_insert", "admit_fetch", "chunk", "dispatch", "drain",
 })
+
+_annotation = None      # jax.profiler.TraceAnnotation, imported on first use
+
+
+def _trace_annotation():
+    """JAX is imported only when a phase is first entered: the gateway
+    shares this module and stays stdlib-only while it does not trace."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
 
 
 class SpanEvent(NamedTuple):
@@ -118,6 +147,43 @@ class _Span:
         return False
 
 
+class _Phase:
+    """Synchronous phase: a profiler annotation ``obs.<kind>`` always, a
+    ring span when the tracer is enabled. ``t0``/``t1`` are the tracer
+    clock's stamps at entry and exit, set either way, so a site that
+    reports its own decomposition (a pool worker's fetch/sample split)
+    reads the very stamps the ring records."""
+
+    __slots__ = ("_tr", "_kind", "_name", "_track", "_args", "_ann",
+                 "t0", "t1")
+
+    def __init__(self, tracer: "StepTracer", kind: str, name: Optional[str],
+                 track: Optional[str], args: dict):
+        if kind not in SPAN_KINDS:
+            raise ValueError(f"unknown span kind {kind!r}; taxonomy: "
+                             f"{sorted(SPAN_KINDS)} (DESIGN.md §17)")
+        self._tr = tracer
+        self._kind = kind
+        self._name = name
+        self._track = track
+        self._args = args
+
+    def __enter__(self) -> "_Phase":
+        self._ann = _trace_annotation()("obs." + self._kind, **self._args)
+        self._ann.__enter__()
+        self.t0 = self._tr.clock()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = self._tr.clock()
+        self._ann.__exit__(*exc)
+        tr = self._tr
+        if tr.enabled:
+            tr._record(self._kind, self._name, "X", self.t0,
+                       max(0.0, self.t1 - self.t0), self._track, self._args)
+        return False
+
+
 class StepTracer:
     """Flight recorder of :class:`SpanEvent` items in a bounded ring
     buffer (``capacity`` most recent events; oldest evicted first).
@@ -157,6 +223,15 @@ class StepTracer:
         if not self._enabled:
             return NULL_SPAN
         return _Span(self, kind, name, track, args)
+
+    def phase(self, kind: str, name: Optional[str] = None,
+              track: Optional[str] = None, **args) -> _Phase:
+        """Context manager for a synchronous phase: always a profiler
+        annotation ``obs.<kind>`` carrying ``args`` (small integers) as
+        its metadata, plus a ring span when enabled. Without a profiler
+        session the annotation costs one object and one "is a trace
+        active" check."""
+        return _Phase(self, kind, name, track, args)
 
     def add(self, kind: str, t0: float, t1: float,
             name: Optional[str] = None, track: Optional[str] = None,
@@ -207,14 +282,5 @@ class StepTracer:
 NULL_TRACER = StepTracer(capacity=1, enabled=False)
 
 
-def merge_events(sources: Iterable[StepTracer]) -> List[SpanEvent]:
-    """Events from several tracers on one clock, sorted by start time."""
-    out: List[SpanEvent] = []
-    for tr in sources:
-        out.extend(tr.events())
-    out.sort(key=lambda e: e.ts)
-    return out
-
-
 __all__ = ["SPAN_KINDS", "SpanEvent", "StepTracer", "NULL_TRACER",
-           "NULL_SPAN", "merge_events"]
+           "NULL_SPAN"]

@@ -11,6 +11,7 @@ import math
 import re
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 from repro.config import SHVSConfig
@@ -24,7 +25,7 @@ from repro.models.model import Model
 from repro.obs import (DEFAULT_MS_BUCKETS, NULL_SPAN, SPAN_KINDS,
                        CycleRecord, MetricsRegistry, StepRecord, StepTracer,
                        Telemetry, chrome_trace, chrome_trace_events,
-                       merge_events, render_registries, write_chrome_trace)
+                       render_registries, write_chrome_trace)
 
 pytestmark = pytest.mark.obs
 
@@ -165,13 +166,54 @@ def test_unknown_span_kind_rejected():
     assert "forward" in SPAN_KINDS and "stage" in SPAN_KINDS
 
 
-def test_merge_events_sorts_by_start():
-    a = StepTracer(capacity=8, enabled=True)
-    b = StepTracer(capacity=8, enabled=True)
-    a.add("forward", 2.0, 3.0, name="late")
-    b.add("commit", 1.0, 1.5, name="early")
-    merged = merge_events([a, b])
-    assert [e.name for e in merged] == ["early", "late"]
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a real ``jax.profiler`` session and return the
+    host events named ``obs.*`` it wrote, as (name, start, end, stats)
+    sorted by start."""
+    from jax.profiler import ProfileData
+    with jax.profiler.trace(str(tmp_path)):
+        fn()
+    xplane, = tmp_path.rglob("*.xplane.pb")
+    out = []
+    for plane in ProfileData.from_file(str(xplane)).planes:
+        if plane.name.startswith("/host:"):
+            out += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                     dict(e.stats))
+                    for line in plane.lines for e in line.events
+                    if e.name.startswith("obs.")]
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+@pytest.mark.parametrize("enabled", [False, True],
+                         ids=["disabled", "enabled"])
+def test_phase_lands_in_the_profiler_trace(tmp_path, enabled):
+    tr = StepTracer(capacity=16, enabled=enabled)
+    stamps = []
+
+    def run():
+        with tr.phase("dispatch", name="dispatch@7", step=7, rows=3) as ph:
+            pass
+        stamps.append((ph.t0, ph.t1))
+
+    evs = _profiled(tmp_path, run)
+    # the profiler sink always: one annotation, its args as metadata
+    assert [(n, st["step"], st["rows"]) for n, _, _, st in evs] == \
+        [("obs.dispatch", 7, 3)]
+    # the ring only when enabled, on the stamps the phase exposes
+    t0, t1 = stamps[0]
+    assert t0 <= t1
+    if enabled:
+        ev, = tr.events()
+        assert (ev.kind, ev.name, ev.ts, ev.dur) == \
+            ("dispatch", "dispatch@7", t0, t1 - t0)
+        assert dict(ev.args) == {"step": 7, "rows": 3}
+    else:
+        assert len(tr) == 0
+
+
+def test_phase_rejects_unknown_kind():
+    with pytest.raises(ValueError, match="unknown span kind"):
+        StepTracer(enabled=False).phase("admit")
 
 
 # -- Chrome trace export ------------------------------------------------------
@@ -394,6 +436,89 @@ def test_pipeline_emits_stage_spans_per_stage_and_microbatch():
         {"stage", "host_sample", "d2h_transfer", "commit"}
     rep = eng.pipeline_report()                 # CycleRecord consumers
     assert rep["cycles"] > 0 and 0.0 <= rep["bubble_frac"] <= 1.0
+
+
+def _device_engine(telemetry=None, overlap=False):
+    return Engine(smoke_model(), _params(), EngineConfig(
+        max_batch=4, max_seq_len=96, algorithm="reference",
+        shvs=SHVSConfig(hot_size=VOCAB // 4), k_cap=256,
+        overlap=overlap, sampler_mode="device"), telemetry=telemetry)
+
+
+def test_engine_step_phases_in_order_and_nesting(tmp_path):
+    """One sequential step that admits and decodes, with the default
+    (disabled) tracer: the profiler trace holds the step's phases in code
+    order, the admission's children nested inside ``obs.prefill``."""
+    eng = _device_engine()
+    try:
+        eng.submit(_requests(2, max_new=4))
+        eng.step()                  # compile every program outside the trace
+        eng.submit(_requests(1, max_new=4, base_seed=900))
+        eng._pending.clear()
+        evs = _profiled(tmp_path, eng.step)
+    finally:
+        eng.close()
+    assert [e[0] for e in evs] == [
+        "obs.schedule", "obs.prefill", "obs.admit_decide",
+        "obs.admit_insert", "obs.admit_fetch", "obs.dispatch",
+        "obs.drain", "obs.commit"]
+    spans = {e[0]: e for e in evs}
+    _, p0, p1, pst = spans["obs.prefill"]
+    for child in ("obs.admit_decide", "obs.admit_insert", "obs.admit_fetch"):
+        assert p0 <= spans[child][1] <= spans[child][2] <= p1
+    assert pst["rows"] == 1 and pst["padded"] >= pst["tokens"] > 0
+    # top-level phases do not overlap one another
+    top = [e for e in evs if not e[0].startswith("obs.admit_")]
+    assert all(a[2] <= b[1] for a, b in zip(top, top[1:]))
+    assert len(eng.tracer) == 0
+
+
+def test_decode_program_scopes_forward_and_decision():
+    """The decode program's compiled HLO carries the two scopes in its ops'
+    ``op_name``, which is how a profiler trace's ops are split between
+    the model and the decision plane."""
+    eng = _device_engine()
+    try:
+        B = eng.ecfg.max_batch
+        text = eng._decode_jit.lower(
+            eng.params, eng.cache, eng.pstate, eng.last_tokens,
+            eng._sp.as_params(), eng._sp.bias_array(),
+            jnp.asarray(eng._nonce), jnp.asarray(eng._pos),
+            jnp.asarray(0, jnp.int32), jnp.ones((B,), bool)
+        ).compile().as_text()
+    finally:
+        eng.close()
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("forward", "decision"):
+        assert any(scope in p.split("/") for p in paths), scope
+
+
+@pytest.mark.parametrize("make_engine", ["engine", "pipeline"])
+def test_first_token_stamped_after_it_reaches_the_host(make_engine):
+    tel = Telemetry(tracer=StepTracer(capacity=8192, enabled=True))
+    if make_engine == "engine":
+        eng = _device_engine(telemetry=tel, overlap=True)
+    else:
+        eng = PipelineEngine(smoke_model(), _params(), PipelineConfig(
+            stages=2, max_batch=4, max_seq_len=96, algorithm="reference",
+            shvs=SHVSConfig(hot_size=VOCAB // 4), k_cap=256,
+            sampler_mode="host", samplers=2), telemetry=tel)
+    reqs = _requests(3, max_new=3)
+    try:
+        eng.submit(reqs)
+        eng.run()
+    finally:
+        eng.close()
+    evs = tel.tracer.events()
+    for r in reqs:
+        t = r.token_times[0]
+        assert r.first_token_time == t
+        # the stamp lies inside its admission's prefill phase, after that
+        # admission's fetch of the first tokens ended
+        pf, = [e for e in evs if e.kind == "prefill" and e.ts <= t <= e.end]
+        fetch, = [e for e in evs if e.kind == "admit_fetch"
+                  and pf.ts <= e.ts and e.end <= pf.end]
+        assert t >= fetch.end
 
 
 # -- live gateway endpoints ---------------------------------------------------
