@@ -276,6 +276,22 @@ def admission_shape(eng, new_requests: List[Request]):
     return ctxs, min(Sp, eng.ecfg.max_seq_len)
 
 
+def admission_decision(decision: DecisionPlane):
+    """The admission's first-token ``decision.step`` as one jitted program:
+    its operands are shaped by the group size P alone (and by whether the
+    dense bias operand is present), so it compiles once per (P, bias), not
+    per prompt length. Build it anew wherever the decision plane's hot set
+    changes: the hot set is traced into the program, and the fresh closure
+    keeps jax's trace cache from handing back the old one."""
+    def admit_decide(logits, pstate, sparams, step_idx, nonces, positions,
+                     bias):
+        tokens, pstate, _ = decision.step(
+            logits, pstate, sparams, step_idx,
+            rng_tags=(nonces, positions), logit_bias=bias)
+        return tokens, pstate
+    return jax.jit(admit_decide)
+
+
 def prefill_new_rows(eng, new_requests: List[Request], step_idx: int):
     """Shared admission math behind :meth:`Engine._admit` and
     :meth:`PipelineEngine._admit_group` — one implementation so the
@@ -283,9 +299,10 @@ def prefill_new_rows(eng, new_requests: List[Request], step_idx: int):
     requests' contexts, run the monolithic prefill program (jit-cached per
     ``(P, Sp)``), rebuild resumed rows' prompt/output histogram split
     (presence/frequency penalties read C_o — Eq. 5), and sample each row's
-    first token at its resume position. ``eng`` needs ``cfg`` / ``ecfg`` /
-    ``params`` / ``decision`` / ``tracer`` / ``_prefill_cache`` /
-    ``_prefill_impl``.
+    first token at its resume position with the compiled first-token
+    decision (one program per group size P, :func:`admission_decision`).
+    ``eng`` needs ``cfg`` / ``ecfg`` / ``params`` / ``tracer`` /
+    ``_prefill_cache`` / ``_prefill_impl`` / ``_admit_decide_jit``.
 
     Returns ``(first, rows_cache, rows_pstate, lens, bases, rids)`` —
     ``first`` is the (P,) device token array; the caller owns the install
@@ -324,11 +341,10 @@ def prefill_new_rows(eng, new_requests: List[Request], step_idx: int):
     for i, r in enumerate(new_requests):
         sp_rows.set_row(i, r.sampling)
     with eng.tracer.phase("admit_decide", rows=P):
-        first, rows_pstate, _ = eng.decision.step(
+        first, rows_pstate = eng._admit_decide_jit(
             logits, rows_pstate, sp_rows.as_params(),
-            jnp.asarray(step_idx, jnp.int32),
-            rng_tags=(jnp.asarray(rids), jnp.asarray(bases)),
-            logit_bias=sp_rows.bias_array())
+            jnp.asarray(step_idx, jnp.int32), jnp.asarray(rids),
+            jnp.asarray(bases), sp_rows.bias_array())
     return first, rows_cache, rows_pstate, lens, bases, rids
 
 
@@ -521,6 +537,9 @@ class Engine:
         # plane runs in the client's CPU pool on the fetched logits
         self._forward_jit = jax.jit(self._forward_impl,
                                     donate_argnums=(1,) if donate else ())
+        # admission's first-token decision: rebuilt here, with the decode
+        # program, so a hot-set swap reaches it too
+        self._admit_decide_jit = admission_decision(self.decision)
 
     def _state_device(self):
         """Where ``pstate`` lives: the host pool's CPU device in host mode,
@@ -1257,8 +1276,9 @@ class Engine:
 
     def _prefill_rows(self, new_requests: List[Request]) -> None:
         """The prefill half of :meth:`_admit`: run the group's prefill and
-        first-token decision, insert the rows into the batch state, and
-        commit each row's first token once it is on the host."""
+        first-token decision (one compiled program per group size), insert
+        the rows into the batch state, and commit each row's first token
+        once it is on the host."""
         tr = self.tracer
         if tr.enabled:
             # arrival -> admission wait per request (0-stamped offline

@@ -64,8 +64,9 @@ from repro.core.decision_plane import DecisionPlane
 from repro.core.host_sampler import PoolResult, SampleTicket
 from repro.engine.decision_client import DecisionPlaneClient
 from repro.engine.engine import (EngineConfig, SlotParams, _insert_rows,
-                                 admission_shape, generate_stream,
-                                 locked_api, prefill_new_rows)
+                                 admission_decision, admission_shape,
+                                 generate_stream, locked_api,
+                                 prefill_new_rows)
 from repro.engine.paged_cache import (BlockAllocator, PagedCacheConfig,
                                       init_paged_cache)
 from repro.engine.request import Request, RequestState
@@ -327,6 +328,7 @@ class PipelineEngine:
         self._stage_jits = [jax.jit(self._make_stage_impl(s))
                             for s in range(p)]
         self._prefill_cache: Dict[Tuple, callable] = {}
+        self._admit_decide_jit = admission_decision(self.decision)
         self._draining = False
         # bounded typed flight logs (§17): StepRecord per commit,
         # CycleRecord per cycle — a long-lived replica keeps a window
