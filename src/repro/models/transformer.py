@@ -19,6 +19,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout
 
 from repro.config import ModelConfig
 from repro.models import dist
@@ -167,6 +168,33 @@ def _write_kv(cache_k_l, cache_v_l, k, v, lens, mode: str, mask=None):
     return ck, cv
 
 
+def _token_major(cache_k) -> bool:
+    """Whether the default device lays out a stacked ``(L, B, Sc, nkv, hd)``
+    cache with each token's ``(nkv, hd)`` block contiguous: the slot axis
+    major to both head axes. A TPU makes the slot axis minor instead where
+    ``(nkv, hd)`` would pad its tiles (smollm-360m's 5 x 64)."""
+    dev = jax.devices()[0]
+    layout = Layout.from_pjrt_layout(dev.client.get_default_layout(
+        cache_k.dtype, cache_k.shape, dev))
+    return set(layout.major_to_minor[-2:]) == {3, 4}
+
+
+def _write_token(cache, layer, new, slot, token_major: bool):
+    """Write one decode token per row, ``new`` ``(B, 1, nkv, hd)``, into
+    layer ``layer`` of the stacked cache ``(L, B, Sc, nkv, hd)`` at slot
+    ``slot[b]``, in place. Where a token is contiguous on the device
+    (``token_major``) the B tokens are scattered. Where the slot axis is
+    minor, a scatter along it would relayout the whole cache, so the
+    layer's slab is written back whole with the tokens selected in."""
+    if token_major:
+        return cache.at[layer, jnp.arange(new.shape[0]), slot].set(
+            new[:, 0], unique_indices=True)
+    hit = jnp.arange(cache.shape[2])[None, :] == slot[:, None]
+    slab = jnp.where(hit[:, :, None, None], new,
+                     jax.lax.dynamic_index_in_dim(cache, layer, 0, False))
+    return jax.lax.dynamic_update_index_in_dim(cache, slab, layer, 0)
+
+
 # ---------------------------------------------------------------------------
 # Dense / MoE / VLM decoder stack (also whisper decoder via cross_kv)
 # ---------------------------------------------------------------------------
@@ -212,6 +240,12 @@ def apply_dense_stack(params, x, positions, cfg: ModelConfig, cache, mode: str,
     the new K/V land in the pool via an out-of-bounds-dropping scatter.
     ``chunk_counts`` (B,) gives the valid tokens per row of a chunk slab
     (paged chunk writes only; the contiguous slab write doesn't need it).
+
+    Decode on a *contiguous* cache carries the stacked ``k``/``v`` through
+    the layer scan and writes each row's new token in place at
+    ``(layer, b, len[b] % Sc)``; prefill, chunk and the paged pool pass
+    their caches as scan inputs and take the written ones back as outputs
+    (DESIGN.md §19).
     """
     use_ln = cfg.family == "audio"   # whisper uses LayerNorm (bias-free here)
     norm = (lambda h, w: layer_norm(h, w, jnp.zeros_like(w), cfg.rmsnorm_eps)) \
@@ -243,9 +277,16 @@ def apply_dense_stack(params, x, positions, cfg: ModelConfig, cache, mode: str,
         # one (B, C) destination map shared by every layer's pool scatter
         pool_flat = flat_block_indices(bt, lens0, pool_valid, blk, nblocks)
     compute_cross = cfg.is_encdec and mode in ("train", "prefill")
+    # a scan's xs and ys never share a buffer, so a contiguous cache passed
+    # through them is sliced, copied and re-stacked whole on every step;
+    # decode adds one token per row, so it carries the cache instead
+    in_place = cache is not None and mode == "decode" and not paged
+    if in_place:
+        slot = (lens0 % cache["k"].shape[2]).astype(jnp.int32)
+        token_major = _token_major(cache["k"])
 
     def body(carry, xs):
-        x, aux = carry
+        x, aux, kv = carry
         lp = xs["layer"]
         h = norm(x, lp["ln1"])
         if mode == "train":
@@ -258,13 +299,19 @@ def apply_dense_stack(params, x, positions, cfg: ModelConfig, cache, mode: str,
                 # standard decode/chunk attention below runs on it unchanged
                 ck_in = gather_block_view(xs["kp"], bt, blk)
                 cv_in = gather_block_view(xs["vp"], bt, blk)
-            else:
+            elif not in_place:
                 ck_in, cv_in = xs["ck"], xs["cv"]
             if mode == "decode":
                 # write first so the current token attends to itself
                 _, k, v = attention_block(lp["attn"], h, cfg, positions,
                                           mode="train", window=win)  # project only
-                ck, cv = _write_kv(ck_in, cv_in, k, v, lens0, "decode")
+                if in_place:
+                    kv = tuple(_write_token(c, xs["l"], t, slot, token_major)
+                               for c, t in zip(kv, (k, v)))
+                    ck, cv = (jax.lax.dynamic_index_in_dim(c, xs["l"], 0,
+                                                           False) for c in kv)
+                else:
+                    ck, cv = _write_kv(ck_in, cv_in, k, v, lens0, "decode")
                 attn_out, _, _ = attention_block(
                     lp["attn"], h, cfg, positions, cache_k=ck, cache_v=cv,
                     kv_len=kv_len, mode="decode", window=win)
@@ -304,7 +351,7 @@ def apply_dense_stack(params, x, positions, cfg: ModelConfig, cache, mode: str,
         x = x + ff
         x = dist.constrain(x, dist.batch_spec_entry(), None, None)
         ys = {}
-        if ck is not None:
+        if ck is not None and not in_place:
             if paged:
                 # persist only the new tokens: scatter them into the pool
                 # (the gathered view ck/cv was a per-iteration temporary)
@@ -314,7 +361,7 @@ def apply_dense_stack(params, x, positions, cfg: ModelConfig, cache, mode: str,
                 ys["ck"], ys["cv"] = ck, cv
         if cfg.is_encdec and compute_cross and cache is not None:
             ys["cross_k"], ys["cross_v"] = ys_cross
-        return (x, aux), ys
+        return (x, aux, kv), ys
 
     if remat and mode == "train":
         body = jax.checkpoint(body)
@@ -322,19 +369,26 @@ def apply_dense_stack(params, x, positions, cfg: ModelConfig, cache, mode: str,
     layer_tree = {k: v for k, v in params.items()
                   if k != "final_ln"}
     xs = {"layer": layer_tree}
+    kv = None
     if cache is not None:
         if paged:
             xs["kp"], xs["vp"] = cache["k_pool"], cache["v_pool"]
+        elif in_place:
+            kv = (cache["k"], cache["v"])
+            xs["l"] = jnp.arange(cache["k"].shape[0])
         else:
             xs["ck"], xs["cv"] = cache["k"], cache["v"]
         if cfg.is_encdec and not compute_cross:
             xs["cross_k"], xs["cross_v"] = cache["cross_k"], cache["cross_v"]
 
-    (x, aux), ys = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
-    if cache is not None and mode != "train" and ("ck" in ys or "kp" in ys):
+    (x, aux, kv), ys = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), kv), xs)
+    if cache is not None and mode != "train":
         cache = dict(cache)
-        if "kp" in ys:
+        if paged:
             cache["k_pool"], cache["v_pool"] = ys["kp"], ys["vp"]
+        elif in_place:
+            cache["k"], cache["v"] = kv
         else:
             cache["k"], cache["v"] = ys["ck"], ys["cv"]
         if "cross_k" in ys:
