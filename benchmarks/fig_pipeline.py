@@ -101,8 +101,8 @@ def validate(stages: int, microbatches: int, emit_fn) -> None:
     so the comparison isolates the cycle *structure* (Eq. 4 vs the slack
     formula), not the constants."""
     from benchmarks.pipeline_sim import SimConfig, _cycle
-    base = measure(stages, microbatches, "baseline")
-    simple = measure(stages, microbatches, "disaggregated")
+    base = measure(stages, microbatches, "device")
+    simple = measure(stages, microbatches, "host")
     # measured components (s): forward = mean stage busy NET of sampling
     t_stage = (np.mean(base["stage_util"]) * base["mean_cycle_ms"]
                - base["sample_ms_mean"] / stages) * 1e-3
@@ -129,8 +129,8 @@ def validate(stages: int, microbatches: int, emit_fn) -> None:
 
 def run(emit_fn=emit) -> None:
     for p, M in ((2, 4), (4, 8)):
-        base = measure(p, M, "baseline")
-        simple = measure(p, M, "disaggregated")
+        base = measure(p, M, "device")
+        simple = measure(p, M, "host")
         tag = f"p{p}_m{M}"
         emit_fn(f"fig_pipeline.bubble.{tag}.baseline",
                 base["bubble_frac"] * 1e6,

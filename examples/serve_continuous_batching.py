@@ -75,7 +75,7 @@ def main():
     params = Model(cfg).init(jax.random.PRNGKey(0))
     print(f"{'algorithm':18s} {'tok/s':>8s} {'P50 ms':>8s} {'P95 ms':>8s} "
           f"{'1st ev ms':>10s}  finish_reasons")
-    for algo in ("reference", "truncation_first", "shvs"):
+    for algo in ("reference", "shvs"):
         r = run(algo, params, cfg)
         finish = ",".join(f"{k}={v}" for k, v in sorted(r["finish"].items()))
         print(f"{r['algorithm']:18s} {r['tok_s']:8.1f} {r['p50_ms']:8.2f} "

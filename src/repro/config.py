@@ -368,12 +368,11 @@ class SamplingConfig:
 
 @dataclass(frozen=True)
 class SHVSConfig:
-    """Speculative hot-vocab sampling configuration (§5.3/§5.4)."""
+    """Speculative hot-vocab sampling configuration (§5.3/§5.4). The
+    containment guard (the fast path must provably contain the filter
+    support) is always on: it is what keeps SHVS exact."""
 
-    enabled: bool = True
     hot_size: int = 0              # 0 -> use sizing model / default heuristic
-    # guard: fast path must provably contain the filter support
-    containment_guard: bool = True
 
     def resolve_hot_size(self, vocab_size: int) -> int:
         if self.hot_size:

@@ -13,13 +13,11 @@ common to all backends —
 
 — while the logits→token draw itself is the backend:
 
-  "reference"        — full-V masked softmax (baseline oracle)
-  "truncation_first" — paper S2 only
-  "shvs"             — S2 + S3 (the full SIMPLE decision plane)
-  "gumbel"           — beyond-paper single-pass Gumbel fast path
-  "fused"            — the whole pipeline in one Pallas pass (§14); its
-                       ``fuses_penalties`` flag moves the Eq. 1 penalty
-                       application from the shell into the kernel
+  "reference" — full-V masked softmax (the oracle tests compare with)
+  "shvs"      — S2 + S3 (the full SIMPLE decision plane; what serves)
+  "fused"     — the whole pipeline in one Pallas pass (§14); its
+                ``fuses_penalties`` flag moves the Eq. 1 penalty
+                application from the shell into the kernel
 
 The service is a separate jitted program from the model forward — the
 runtime can dispatch the next microbatch's forward while sampling for the
@@ -91,7 +89,7 @@ class DecisionPlane:
         if self._backend is None or self._backend_key != key:
             self._backend = make_backend(
                 self.algorithm, vocab_size=self.vocab_size, k_cap=self.k_cap,
-                seed=self.seed, shvs=self.shvs_cfg, hot_set=self.hot_set)
+                shvs=self.shvs_cfg, hot_set=self.hot_set)
             self._backend_key = key
             if self.hot_set is None and hasattr(self._backend, "hot_set"):
                 # surface the backend's default hot set (autotuner reads it)
@@ -102,8 +100,9 @@ class DecisionPlane:
     # -- state ---------------------------------------------------------------
     def init_state(self, batch: int, prompt_tokens=None, prompt_lens=None
                    ) -> pen.PenaltyState:
-        return self._resolve_backend().init_state(
-            batch, self.vocab_size, prompt_tokens, prompt_lens)
+        """Per-batch decision state: the token histograms of Eq. 5."""
+        return pen.init_state(batch, self.vocab_size, prompt_tokens,
+                              prompt_lens)
 
     def uniforms(self, step, batch: int):
         """Deterministic (B, 3) uniforms for (accept, hot, tail) draws."""
@@ -208,14 +207,13 @@ class DecisionPlane:
             # the backend applies Eq. 1 inside its own single pass: hand it
             # raw (post-bias/mask) logits + the histogram state, and never
             # materialize a penalized (B, V) intermediate
-            tokens, stats = backend.step(logits, core, u, step_idx=step_idx,
-                                         state=state)
+            tokens, stats = backend.step(logits, core, u, state=state)
         else:
             z = pen.apply_penalties_rows(logits, state,
                                          core.repetition_penalty,
                                          core.presence_penalty,
                                          core.frequency_penalty)
-            tokens, stats = backend.step(z, core, u, step_idx=step_idx)
+            tokens, stats = backend.step(z, core, u)
         state = pen.update_histograms(state, tokens, active)
         return tokens, state, stats
 

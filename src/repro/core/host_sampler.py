@@ -147,7 +147,7 @@ class HostSamplerPool:
     ``submit`` shards a microbatch's rows across the workers
     (sequence-parallel, S1) and returns a ticket; ``sample_sync`` runs the
     identical math full-width on the calling thread — the pipeline
-    engine's ``baseline`` mode (sampling synchronously on the last stage,
+    engine's ``device`` mode (sampling synchronously on the last stage,
     Eq. 4) and the two paths are bit-identical by construction.
 
     Placement: ``submit`` commits the fetched logits, the penalty state,
@@ -156,23 +156,11 @@ class HostSamplerPool:
     default device is; the returned state stays there, and the owning
     engine keeps it there between steps. ``sample_sync`` moves every
     operand to the logits' own device instead.
-
-    ``backend_override`` selects a different registered sampler backend
-    for the POOL only (e.g. ``"fused"`` to run the single-pass kernel on
-    the host workers while the engine's own plane keeps its configured
-    algorithm). The override plane is cloned from the engine's plane at
-    every :meth:`refresh` — same seed, k_cap, SHVS config, and CURRENT
-    hot set — so its uniforms and histograms are bit-compatible and
-    autotune hot-set swaps propagate through the ordinary refresh hook.
-    Unknown names fail at construction (the registry's ``ValueError``),
-    not on a worker thread mid-serve.
     """
 
     def __init__(self, plane: DecisionPlane, num_workers: int = 2,
-                 backend_override: Optional[str] = None,
                  tracer: Optional[StepTracer] = None):
         self.plane = plane
-        self.backend_override = backend_override
         self.device = jax.devices("cpu")[0]
         self.num_workers = max(1, num_workers)
         # the owning engine's flight recorder (§17): workers record their
@@ -183,27 +171,13 @@ class HostSamplerPool:
         self._closed = False
         self.refresh()
 
-    def _decision_plane(self) -> DecisionPlane:
-        """The plane the workers actually run: the engine's, or a clone
-        carrying the pool-level backend override."""
-        if self.backend_override is None:
-            return self.plane
-        return DecisionPlane(
-            self.plane.vocab_size, algorithm=self.backend_override,
-            shvs=self.plane.shvs_cfg, hot_set=self.plane.hot_set,
-            sampling_parallelism=self.plane.parallelism,
-            k_cap=self.plane.k_cap, seed=self.plane.seed)
-
     def refresh(self) -> None:
         """(Re-)jit the worker-side decision program. Call after the
         plane's configuration changed under the pool — e.g. the SHVS
         autotuner swapping ``hot_set`` — since the traced program captured
-        the backend (and, with an override, the cloned plane) as of trace
-        time."""
-        plane = self._decision_plane()
-
+        the backend as of trace time."""
         def _step(logits, state, params, bias, nonces, pos, step, active):
-            tokens, state, stats = plane.step(
+            tokens, state, stats = self.plane.step(
                 logits, state, params, step, active=active,
                 rng_tags=(nonces, pos), logit_bias=bias)
             tokens = jnp.where(active, tokens, 0)
@@ -285,9 +259,9 @@ class HostSamplerPool:
 
     def sample_sync(self, logits, state, params, bias, nonces, pos, step,
                     active) -> PoolResult:
-        """Full-width draw on the calling thread (device/baseline mode):
-        the same decision program, blocking the caller's cycle on the
-        result."""
+        """Full-width draw on the calling thread (the pipeline's device
+        mode): the same decision program, blocking the caller's cycle on
+        the result."""
         R = logits.shape[0]
         part = self._run_shard(0, R, logits, state, params, bias, nonces,
                                pos, step, active, on_host=False)

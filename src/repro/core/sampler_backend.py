@@ -5,26 +5,27 @@ disaggregated from the data plane (§1, §4.2). This module is the narrow,
 versioned contract that makes the claim concrete (DESIGN.md §11):
 
 * :class:`SamplerBackend` — the protocol. A backend is a stateless
-  logits→token draw: ``init_state`` builds the per-batch penalty state,
-  ``step(z, params, uniforms, step_idx=...)`` turns penalized logits into
-  ``(tokens, DecisionStats)``. Everything around the draw — pre-generated
-  uniforms, penalties, S1 re-sharding, histogram updates, constrained-
-  decoding masks — is owned by the service shell (`DecisionPlane`), so a
-  backend is exactly one interchangeable sampling algorithm.
+  logits→token draw: ``step(z, params, uniforms)`` turns penalized logits
+  into ``(tokens, DecisionStats)``. Everything around the draw —
+  pre-generated uniforms, penalties, S1 re-sharding, the histogram state
+  and its updates, constrained-decoding masks — is owned by the service
+  shell (`DecisionPlane`), so a backend is exactly one interchangeable
+  sampling algorithm.
 * a **registry** — backends are selected by name
   (:func:`make_backend` / :func:`registered_backends`); an unknown name is
   a `ValueError` listing what is registered, never a silent fall-through.
 
-Built-in backends:
+Built-in backends, each kept for one reason:
 
-  ``reference``         full-V masked softmax (the baseline oracle)
-  ``truncation_first``  the paper's S2 (truncate → normalize → draw)
-  ``shvs``              S2 + S3 speculative hot-vocab sampling
-                        (registered by ``repro.core.shvs``)
-  ``gumbel``            beyond-paper single-pass Gumbel argmax fast path
-  ``fused``             the whole decision in ONE Pallas pass — penalties →
-                        temperature → truncation-first filter → Gumbel draw
-                        (``kernels/fused_kernel.py``, DESIGN.md §14)
+  ``reference``  full-V masked softmax — the oracle the tests compare with
+  ``shvs``       S2 + S3 speculative hot-vocab sampling — what serves
+                 (registered by ``repro.core.shvs``); rows whose filter
+                 support leaves the hot set fall back to the paper's S2
+                 (``truncation_first_sample``) on the full vocabulary
+  ``fused``      the whole decision in ONE Pallas pass — penalties →
+                 temperature → truncation-first filter → Gumbel draw
+                 (``kernels/fused_kernel.py``, DESIGN.md §14) — the
+                 single-pass candidate against SHVS's full-V fallback
 
 Contract invariants (pinned by ``tests/test_service_api.py``):
 
@@ -39,14 +40,11 @@ Contract invariants (pinned by ``tests/test_service_api.py``):
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, NamedTuple, Optional, Tuple
+from typing import Callable, Dict, NamedTuple, Tuple
 
-import jax
 import jax.numpy as jnp
 
-from repro.core import penalties as pen
-from repro.core.sampling import (SamplingParams, sample_reference,
-                                 temperature_scale, truncation_first_sample)
+from repro.core.sampling import SamplingParams, sample_reference
 
 
 class DecisionStats(NamedTuple):
@@ -63,7 +61,7 @@ class SamplerBackend:
     Subclasses set ``name`` (the registry key) and implement :meth:`step`.
     Constructors are invoked by the registry with the full service
     configuration as keyword arguments — ``vocab_size``, ``k_cap``,
-    ``seed``, ``shvs`` (an ``SHVSConfig``), ``hot_set`` — and take what
+    ``shvs`` (an ``SHVSConfig``), ``hot_set`` — and take what
     they need (accept ``**_`` for the rest), so new backends can add
     knobs without touching the engine.
     """
@@ -78,14 +76,8 @@ class SamplerBackend:
     #: materializing a penalized (B, V) intermediate.
     fuses_penalties: bool = False
 
-    def init_state(self, batch: int, vocab_size: int, prompt_tokens=None,
-                   prompt_lens=None) -> pen.PenaltyState:
-        """Per-batch decision state (token histograms for Eq. 5)."""
-        return pen.init_state(batch, vocab_size, prompt_tokens, prompt_lens)
-
     def step(self, z: jnp.ndarray, params: SamplingParams,
-             uniforms: jnp.ndarray, *, step_idx) -> Tuple[jnp.ndarray,
-                                                          DecisionStats]:
+             uniforms: jnp.ndarray) -> Tuple[jnp.ndarray, DecisionStats]:
         """Draw one token per row.
 
         ``z``: penalized (NOT temperature-scaled) logits (B, V) f32 — or,
@@ -95,8 +87,6 @@ class SamplerBackend:
         ``uniforms``: (B, 3) pre-generated uniforms — (accept, hot, tail)
         draws; backends that need fewer use a fixed subset so unrelated
         backends never contend for the same stream.
-        ``step_idx``: the global iteration index (only the ``gumbel``
-        backend keys anything on it).
         Returns ``(tokens (B,) int32, DecisionStats)``.
         """
         raise NotImplementedError
@@ -156,27 +146,10 @@ class ReferenceBackend(SamplerBackend):
     def __init__(self, **_):
         pass
 
-    def step(self, z, params, uniforms, *, step_idx):
+    def step(self, z, params, uniforms):
         tokens = sample_reference(z, params, uniforms[:, 1])
         stats = DecisionStats(jnp.ones(()), jnp.ones(()), jnp.zeros(()))
         return tokens, stats
-
-
-@register_backend("truncation_first")
-class TruncationFirstBackend(SamplerBackend):
-    """The paper's S2: truncate to the filter support, then draw (§5.2)."""
-
-    name = "truncation_first"
-
-    def __init__(self, *, k_cap: int = 1024, **_):
-        self.k_cap = k_cap
-
-    def step(self, z, params, uniforms, *, step_idx):
-        res = truncation_first_sample(z, params, uniforms[:, 1],
-                                      k_cap=self.k_cap)
-        stats = DecisionStats(jnp.ones(()), jnp.ones(()),
-                              1.0 - res.exact.mean())
-        return res.tokens, stats
 
 
 @register_backend("fused")
@@ -194,7 +167,7 @@ class FusedBackend(SamplerBackend):
     and the candidate's vocab id, so tokens obey the engine's
     batch-composition and cross-mode determinism contracts.
 
-    ``hot_set`` defaults exactly like the ``shvs`` backend so the fused
+    ``hot_set`` defaults like the ``shvs`` backend's so the fused
     pass reports the same α statistic and plugs into the
     ``HotSizeController`` autotune loop; re-resolution on hot-set swap
     re-specializes the kernel (pinned by ``tests/test_fused_backend.py``).
@@ -205,18 +178,14 @@ class FusedBackend(SamplerBackend):
 
     def __init__(self, *, vocab_size: int, k_cap: int = 1024, shvs=None,
                  hot_set=None, block_b: int = 8, block_v: int = 2048, **_):
-        if hot_set is None:
-            from repro.config import SHVSConfig
-            from repro.core.shvs import make_hot_set
-            cfg = shvs if shvs is not None else SHVSConfig()
-            H = cfg.resolve_hot_size(vocab_size)
-            hot_set = make_hot_set(jnp.arange(H, dtype=jnp.int32), vocab_size)
-        self.hot_set = hot_set
+        from repro.core.shvs import default_hot_set
+        self.hot_set = hot_set if hot_set is not None else \
+            default_hot_set(vocab_size, shvs)
         self.k_cap = k_cap
         self.block_b = block_b
         self.block_v = block_v
 
-    def step(self, z, params, uniforms, *, step_idx, state):
+    def step(self, z, params, uniforms, *, state):
         from repro.kernels import ops
         tokens, exact, alpha, kept = ops.fused_sample(
             z, state.prompt_counts, state.output_counts, params,
@@ -224,40 +193,4 @@ class FusedBackend(SamplerBackend):
             block_b=self.block_b, block_v=self.block_v)
         stats = DecisionStats(jnp.ones(()), alpha.mean(),
                               1.0 - exact.mean())
-        return tokens, stats
-
-
-@register_backend("gumbel")
-class GumbelBackend(SamplerBackend):
-    """Beyond-paper single-pass sampler: unfiltered rows draw via
-    argmax(z + Gumbel) (one HBM pass, no normalization/sort —
-    ``kernels/gumbel_kernel.py``); filtered rows take the
-    truncation-first path.
-
-    The Gumbel fast path seeds on ``(seed, step_idx)`` — reproducible
-    run-to-run but excluded from the cross-mode identity contract for
-    unfiltered stochastic rows (DESIGN.md §2).
-    """
-
-    name = "gumbel"
-
-    def __init__(self, *, k_cap: int = 1024, seed: int = 0, **_):
-        self.k_cap = k_cap
-        self.seed = seed
-
-    def step(self, z, params, uniforms, *, step_idx):
-        from repro.kernels.ref import gumbel_argmax_ref
-        zs = temperature_scale(z, params.temperature)
-        seed32 = jnp.asarray(self.seed, jnp.int32) * 1000003 + \
-            jnp.asarray(step_idx, jnp.int32)
-        fast = gumbel_argmax_ref(zs, seed32)
-        res = truncation_first_sample(z, params, uniforms[:, 1],
-                                      k_cap=self.k_cap)
-        has_filter = (params.top_k > 0) | (params.top_p < 1.0) | \
-            (params.min_p > 0.0)
-        greedy = jnp.argmax(zs, axis=-1).astype(jnp.int32)
-        tokens = jnp.where(params.temperature <= 0.0, greedy,
-                           jnp.where(has_filter, res.tokens, fast))
-        stats = DecisionStats((~has_filter).mean(), jnp.ones(()),
-                              (has_filter & ~res.exact).mean())
         return tokens, stats

@@ -10,9 +10,10 @@ Math (Eq. 6–9): with penalized/scaled logits z and stable weights
 
 TPU adaptation (see DESIGN.md): on TPU the expensive decision-plane op is the
 *sort* (top-k/top-p over V up to 202k), not the single streaming pass. SHVS
-keeps one cheap O(V) vectorized pass (exp + segmented sums + tail max — fused
-in the Pallas kernel ``kernels/shvs_kernel.py``) and confines all sort-based
-work to the H-sized hot block.
+keeps one cheap O(V) vectorized pass (exp + segmented sums + tail max) and
+confines all sort-based work to the H-sized hot block — except for rows whose
+filter support leaves the hot set, which take the full-V truncation-first
+fallback (the paper's S2) and its sort.
 
 Filter interaction (beyond-paper refinement, §7 of DESIGN.md): with top-k /
 top-p enabled, the hot fast path is provably exact iff the global filter
@@ -54,6 +55,16 @@ def make_hot_set(indices: jnp.ndarray, vocab_size: int) -> HotSet:
     return HotSet(indices=indices, mask=mask)
 
 
+def default_hot_set(vocab_size: int, shvs=None) -> HotSet:
+    """The hot set a backend takes when none is given: the contiguous
+    low-id prefix (tokenizers assign low ids to frequent tokens) sized by
+    the SHVS config. Real deployments pass a trace-built set."""
+    from repro.config import SHVSConfig
+    H = (shvs if shvs is not None else SHVSConfig()).resolve_hot_size(
+        vocab_size)
+    return make_hot_set(jnp.arange(H, dtype=jnp.int32), vocab_size)
+
+
 class SHVSResult(NamedTuple):
     tokens: jnp.ndarray      # (B,) int32
     accepted: jnp.ndarray    # (B,) bool — fast path produced the token
@@ -68,8 +79,8 @@ def shvs_masses(z: jnp.ndarray, hot: HotSet):
     """The single streaming pass over V (Eq. 6–7): returns
     (m, S_hot, S_tail, tail_max) with shapes ((B,),...).
 
-    This is the op the Pallas kernel ``shvs_kernel`` fuses; the pure-jnp form
-    here is its oracle and the non-kernel execution path.
+    The ``fused`` backend's kernel streams the hot and total masses tile
+    by tile (``kernels/ref.streaming_mass_update``) inside its single pass.
     """
     m = jnp.max(z, axis=-1)
     w = jnp.exp(z - m[:, None])
@@ -171,24 +182,18 @@ class SHVSBackend(SamplerBackend):
     """S2 + S3 — the full SIMPLE decision plane as a sampler backend.
 
     Registered here (not in ``sampler_backend``) so the backend lives next
-    to the math it wraps. ``hot_set`` defaults to a contiguous low-id set
-    sized by the SHVS config (tokenizers assign low ids to frequent
-    tokens); real deployments pass a trace-built set.
+    to the math it wraps. ``hot_set`` defaults to :func:`default_hot_set`.
     """
 
     name = "shvs"
 
     def __init__(self, *, vocab_size: int, k_cap: int = 1024, shvs=None,
                  hot_set: Optional[HotSet] = None, **_):
-        if hot_set is None:
-            from repro.config import SHVSConfig
-            cfg = shvs if shvs is not None else SHVSConfig()
-            H = cfg.resolve_hot_size(vocab_size)
-            hot_set = make_hot_set(jnp.arange(H, dtype=jnp.int32), vocab_size)
-        self.hot_set = hot_set
+        self.hot_set = hot_set if hot_set is not None else \
+            default_hot_set(vocab_size, shvs)
         self.k_cap = k_cap
 
-    def step(self, z, params, uniforms, *, step_idx):
+    def step(self, z, params, uniforms):
         res = shvs_sample(z, params, self.hot_set, uniforms[:, 0],
                           uniforms[:, 1], uniforms[:, 2], k_cap=self.k_cap)
         stats = DecisionStats(res.accepted.mean(), res.alpha.mean(),

@@ -8,16 +8,16 @@ one of two modes:
   the engine's own program chain. The single-stage :class:`Engine` fuses
   ``DecisionPlane.step`` into its jitted decode program (the §2 overlapped
   loop); the :class:`PipelineEngine` runs it full-width on the calling
-  thread right after the last stage's forward (the paper's Eq. 4 baseline,
-  historically ``sampler_mode="baseline"``).
+  thread right after the last stage's forward (the paper's Eq. 4
+  baseline).
 * ``host`` — the paper's disaggregation: logits are ``device_get``'d and a
   :class:`~repro.core.host_sampler.HostSamplerPool` of CPU workers runs
   sequence-parallel row shards through the identical
   :class:`~repro.core.decision_plane.DecisionPlane`. ``submit`` never
   blocks; the engine collects the :class:`SampleTicket` one step (or one
   pipeline re-entry) later, so CPU sampling for step *t* overlaps the
-  host-side work — and any still-in-flight device compute — of step *t+1*
-  (historically ``sampler_mode="disaggregated"``).
+  host-side work — and any still-in-flight device compute — of step
+  *t+1*.
 
 The two modes are bit-identical by construction: every per-row decision
 computation (penalties, filters, the backend draw, the Eq. 5 histogram
@@ -40,25 +40,19 @@ from repro.obs.tracer import StepTracer
 #: kernel only runs in the Pallas interpreter
 PALLAS_BACKENDS = ("fused",)
 
-#: accepted ``sampler_mode`` spellings -> canonical client mode. The
-#: pipeline's original names stay valid so existing configs don't break.
-SAMPLER_MODES = {
-    "device": "device",
-    "host": "host",
-    "baseline": "device",
-    "disaggregated": "host",
-}
+#: the client's placements (``"adaptive"`` is the engines' own: it starts
+#: in one of these and a controller switches between them)
+SAMPLER_MODES = ("device", "host")
 
 
 def canonical_sampler_mode(mode: str) -> str:
-    """Map a ``sampler_mode`` spelling to ``device`` | ``host``; unknown
-    names raise a ``ValueError`` listing the accepted spellings."""
-    try:
-        return SAMPLER_MODES[mode]
-    except KeyError:
+    """Check a ``sampler_mode``: ``device`` | ``host`` pass through;
+    anything else raises a ``ValueError`` listing the accepted modes."""
+    if mode not in SAMPLER_MODES:
         raise ValueError(
             f"unknown sampler_mode {mode!r}; expected one of "
-            f"{sorted(SAMPLER_MODES)}") from None
+            f"{list(SAMPLER_MODES)}")
+    return mode
 
 
 class DecisionPlaneClient:
@@ -70,12 +64,7 @@ class DecisionPlaneClient:
     executor threads are started lazily on the first host-mode ``submit``,
     so a device-mode client costs nothing.
 
-    ``pool_algorithm`` applies a pool-level backend override: host-mode
-    workers draw with that registered backend (e.g. the single-pass
-    ``fused`` kernel) while the engine's own plane keeps its configured
-    algorithm — the ``--pool-algorithm`` serving knob (DESIGN.md §14).
-
-    On a machine with an accelerator, a pool whose draw is a Pallas kernel
+    On a machine with an accelerator, a plane whose draw is a Pallas kernel
     is refused (``ValueError``) wherever host placement can happen: at
     construction in host mode or when ``switchable`` (the adaptive
     controller may move the decision to the host later), and at
@@ -84,16 +73,13 @@ class DecisionPlaneClient:
     """
 
     def __init__(self, plane: DecisionPlane, mode: str = "device",
-                 workers: int = 2, pool_algorithm: Optional[str] = None,
-                 tracer: Optional[StepTracer] = None,
+                 workers: int = 2, tracer: Optional[StepTracer] = None,
                  switchable: bool = False):
         self.mode = canonical_sampler_mode(mode)
         self.plane = plane
         # the engine's flight recorder rides through to the pool workers
         # (§17) so their fetch/sample spans land in the same trace
-        self.pool = HostSamplerPool(plane, workers,
-                                    backend_override=pool_algorithm,
-                                    tracer=tracer)
+        self.pool = HostSamplerPool(plane, workers, tracer=tracer)
         if self.is_host or switchable:
             self._refuse_pallas_on_host()
         self._tickets: List[SampleTicket] = []   # outstanding host work
@@ -103,13 +89,13 @@ class DecisionPlaneClient:
         return self.mode == "host"
 
     def _refuse_pallas_on_host(self) -> None:
-        algorithm = self.pool.backend_override or self.plane.algorithm
+        algorithm = self.plane.algorithm
         if algorithm in PALLAS_BACKENDS and jax.default_backend() != "cpu":
             raise ValueError(
                 f"sampler backend {algorithm!r} is a Pallas kernel and "
                 "cannot run on the host sampler pool's CPU of a "
-                f"{jax.default_backend()} machine; use a jnp backend for "
-                "the pool (e.g. 'shvs') or keep the decision on the device")
+                f"{jax.default_backend()} machine; use a jnp backend "
+                "(e.g. 'shvs') or keep the decision on the device")
 
     # -- the async surface ---------------------------------------------------
     def submit(self, logits, state, params, bias, nonces: np.ndarray,

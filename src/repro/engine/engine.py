@@ -25,9 +25,7 @@ iteration drains immediately (the classic synchronous loop).
 Determinism: uniforms are keyed on (request-id, output position) —
 ``DecisionPlane.uniforms_tagged`` — so the token stream of every request is
 bit-identical between overlapped and sequential mode, and invariant to slot
-placement and admission timing. Exception: the beyond-paper ``gumbel``
-algorithm seeds its fast path on the global iteration index, so it is
-reproducible run-to-run but excluded from the cross-mode identity contract.
+placement and admission timing, for every registered backend.
 
 **Paged KV mode** (``cache="paged"``, DESIGN.md §9). The per-slot slab
 cache is replaced by a vLLM-style block pool: the scheduler admits by free
@@ -120,11 +118,6 @@ class EngineConfig:
     #                                  pool online from the engine's own
     #                                  stat streams)
     samplers: int = 2                # host-mode sampler pool workers
-    pool_algorithm: Optional[str] = None   # pool-level backend override:
-    #                                  host-mode workers draw with this
-    #                                  registered backend (e.g. "fused")
-    #                                  while the engine plane keeps
-    #                                  ``algorithm`` (DESIGN.md §14)
     stats_window: int = 4096         # stats_log / cycle_log ring size: a
     #                                  long-lived gateway replica keeps the
     #                                  most recent window, never grows (§17)
@@ -467,8 +460,8 @@ class Engine:
         self.client = DecisionPlaneClient(
             self.decision,
             "device" if self._adaptive else engine_cfg.sampler_mode,
-            engine_cfg.samplers, pool_algorithm=engine_cfg.pool_algorithm,
-            tracer=self.tracer, switchable=self._adaptive)
+            engine_cfg.samplers, tracer=self.tracer,
+            switchable=self._adaptive)
         self._host = self.client.is_host
         self._metrics.mode_host.set(1.0 if self._host else 0.0)
         self._metrics.pool_workers.set(float(engine_cfg.samplers))

@@ -22,7 +22,7 @@ cycle — was previously reproduced only by the analytic simulator in
   the sampled tokens are **committed only when the microbatch re-enters
   stage 1**, ``(M − p)`` cycles later — the paper's slack. The pipeline
   stalls only if the pool cannot make that slack, and the stall is
-  measured (``cycle_log``). ``sampler_mode="baseline"`` instead samples
+  measured (``cycle_log``). ``sampler_mode="device"`` instead samples
   synchronously right after the last stage's forward, putting
   ``t_sampling`` back on the cycle critical path for the bubble
   comparison (``benchmarks/fig_pipeline.py``).
@@ -85,10 +85,10 @@ class PipelineConfig(EngineConfig):
     stages: int = 2                   # p — pipeline stages
     microbatches: int = 0             # M in flight; 0 -> p (minimum legal)
     samplers: int = 2                 # m — host sampler pool workers
-    sampler_mode: str = "disaggregated"   # -> client "host"; "baseline"
-    #                                   -> "device" (sync, last stage, Eq. 4);
-    #                                   "adaptive" -> §15 controller switches
-    #                                   placement / resizes the pool online
+    sampler_mode: str = "host"        # "device" samples synchronously on the
+    #                                   last stage (Eq. 4); "adaptive" -> §15
+    #                                   controller switches placement /
+    #                                   resizes the pool online
 
 
 @dataclass
@@ -259,9 +259,8 @@ class PipelineEngine:
             k_cap=min(engine_cfg.k_cap, model_cfg.vocab_size),
             seed=engine_cfg.seed)
         # the unified decision-plane client (§13): "host" ships last-stage
-        # logits to the CPU sampler pool ("disaggregated" is the historic
-        # spelling); "device" samples synchronously on the last stage's
-        # critical path ("baseline", Eq. 4)
+        # logits to the CPU sampler pool; "device" samples synchronously on
+        # the last stage's critical path (the paper's baseline, Eq. 4)
         # "adaptive" (§15) starts on host — the pipeline's structural win
         # (Eq. 4: synchronous sampling caps the cycle) — and lets the
         # controller fall back to device / resize the pool online
@@ -274,8 +273,7 @@ class PipelineEngine:
         self.client = DecisionPlaneClient(
             self.decision,
             "host" if self._adaptive else engine_cfg.sampler_mode,
-            engine_cfg.samplers, pool_algorithm=engine_cfg.pool_algorithm,
-            tracer=self.tracer)
+            engine_cfg.samplers, tracer=self.tracer)
         self.pool = self.client.pool
         self._metrics.mode_host.set(1.0 if self.client.is_host else 0.0)
         self._metrics.pool_workers.set(float(engine_cfg.samplers))

@@ -1,14 +1,13 @@
-"""Pallas TPU kernels for the decision plane's compute hot-spots.
+"""Pallas TPU kernel for the decision plane's single-pass decision.
 
-The paper's §5.2 single-pass CPU kernels become, on TPU, fused
-HBM-streaming Pallas kernels with explicit BlockSpec VMEM tiling:
+The paper's §5.2 single-pass CPU kernels become, on TPU, a fused
+HBM-streaming Pallas kernel with explicit BlockSpec VMEM tiling:
 
-* penalty_kernel — fused column-wise penalties + temperature (one pass)
-* shvs_kernel    — the SHVS mass pass (Eq. 6-7): online-softmax rescaled
-                   hot/tail sums + tail max in one pass
-* gumbel_kernel  — beyond-paper single-pass categorical draw (Gumbel-max
-                   with in-VMEM counter-hash noise)
+* fused_kernel — penalties → temperature → streaming top-K + masses →
+                 truncation-first filter → Gumbel-max draw, in one read of
+                 the logits (the ``fused`` sampler backend, DESIGN.md §14)
 
-``ops.py`` holds the jit'd public wrappers (padding + interpret switch);
-``ref.py`` the pure-jnp oracles every kernel is tested against.
+``ops.py`` holds the public wrapper (padding + interpret switch);
+``ref.py`` the pure-jnp oracle the kernel is tested against and the tile
+helpers both share.
 """

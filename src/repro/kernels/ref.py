@@ -1,7 +1,10 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
+"""Pure-jnp oracle for the fused sampling kernel, and the tile helpers
+kernel and oracle share.
 
-These define the exact semantics the kernels must match (asserted by
-``tests/test_kernels.py`` over shape/dtype sweeps in interpret mode).
+``penalty_ref`` is Eq. 1 + temperature in its plain form;
+``fused_sample_ref`` is the tile-faithful oracle the kernel must match bit
+for bit (asserted by ``tests/test_kernels.py`` over shape/dtype sweeps in
+interpret mode).
 """
 from __future__ import annotations
 
@@ -30,26 +33,10 @@ def penalty_ref(logits, counts_p, counts_o, repetition, presence, frequency,
     return z / jnp.maximum(temperature, 1e-6)[:, None]
 
 
-def shvs_mass_ref(z, hot_mask):
-    """The SHVS streaming pass (paper Eq. 6–7): returns
-    (m, s_hot, s_tail, tail_max), each (B,) f32.
-
-    z: (B, V) f32 penalized/scaled logits; hot_mask: (V,) bool.
-    Sums are computed in the stable basis w = exp(z - m).
-    """
-    m = jnp.max(z, axis=-1)
-    w = jnp.exp(z - m[:, None])
-    hotf = hot_mask.astype(jnp.float32)[None, :]
-    s_hot = jnp.sum(w * hotf, axis=-1)
-    s_tail = jnp.sum(w * (1.0 - hotf), axis=-1)
-    tail_max = jnp.max(jnp.where(hot_mask[None, :], NEG_INF, z), axis=-1)
-    return m, s_hot, s_tail, tail_max
-
-
 def _hash_uniform(seed, b, v):
     """Deterministic per-(seed,row,col) uniform in (0,1) via a 32-bit integer
-    hash (xorshift-mix). Shared by the Gumbel kernel and its oracle so both
-    produce bit-identical samples."""
+    hash (xorshift-mix). Shared by the fused kernel's draw and its oracle so
+    both produce bit-identical samples."""
     x = (b.astype(jnp.uint32) * jnp.uint32(2654435761) ^
          v.astype(jnp.uint32) * jnp.uint32(40503) ^
          jnp.uint32(seed))
@@ -71,22 +58,6 @@ def _u32_to_f32(x):
     return hi * 65536.0 + lo
 
 
-def gumbel_argmax_ref(z, seed):
-    """Single-pass categorical draw via the Gumbel-max trick:
-        y = argmax_v ( z_v + G_v ),  G_v = -log(-log(U_v)).
-
-    Distribution-exact for softmax(z) sampling with NO normalization pass —
-    the beyond-paper single-pass sampler (see EXPERIMENTS.md §Perf).
-    z: (B, V) f32; seed: () int32. Returns (tokens (B,) int32).
-    """
-    B, V = z.shape
-    b = jax.lax.broadcasted_iota(jnp.int32, (B, V), 0)
-    v = jax.lax.broadcasted_iota(jnp.int32, (B, V), 1)
-    u = _hash_uniform(seed, b, v)
-    g = -jnp.log(-jnp.log(u))
-    return jnp.argmax(z + g, axis=-1).astype(jnp.int32)
-
-
 # ---------------------------------------------------------------------------
 # Fused single-pass sampler (DESIGN.md §14): penalties → temperature →
 # streaming top-K + masses → truncation-first filter → restricted Gumbel draw.
@@ -97,7 +68,8 @@ def gumbel_argmax_ref(z, seed):
 # ops over the same (block_b, block_v) tile sequence.
 # ---------------------------------------------------------------------------
 
-# decorrelates the fused draw's hash stream from the gumbel backend's
+# salts the fused draw's hash stream; its value keys every fused stream, so
+# changing it moves them all
 FUSED_DRAW_SALT = 0x46555345
 
 
@@ -113,7 +85,8 @@ def _u32_from_uniform(u):
 
 
 def streaming_mass_update(m, s_tot, s_hot, zs, hot_f):
-    """One online-softmax tile step (same rescaling as ``shvs_kernel``):
+    """One online-softmax tile step (the streaming form of
+    ``core.shvs.shvs_masses``):
     carries (m, s_tot, s_hot) — running max and total/hot exp-sums in the
     basis exp(z − m), each a (bb, 1) column. zs: (bb, bv) scaled logits;
     hot_f: (1|bb, bv) f32.

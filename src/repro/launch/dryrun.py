@@ -97,7 +97,7 @@ def main() -> None:
                     choices=("sequence_parallel", "vocab_gather",
                              "hierarchical"))
     ap.add_argument("--algorithm", default="shvs",
-                    choices=("shvs", "truncation_first", "reference"))
+                    choices=("shvs", "reference"))
     ap.add_argument("--all", action="store_true",
                     help="every (arch × shape) combination")
     ap.add_argument("--out", default=None, help="append JSON records here")

@@ -20,8 +20,9 @@ Engine execution mode (DESIGN.md §2/§8/§9/§12):
                                 microbatched PipelineEngine (DESIGN.md §12)
     --microbatches M            microbatches in flight (0 = P); batch % M = 0
     --samplers M                host sampler pool workers (pipeline)
-    --sampler-mode MODE         disaggregated (host pool, default) or
-                                baseline (sync on the last stage, Eq. 4);
+    --sampler-mode MODE         host (CPU sampler pool; the pipeline's
+                                default) or device (on the accelerator; sync
+                                on the last stage under --stages, Eq. 4);
                                 adaptive = §15 controller switches placement
                                 and pool size online from the stat streams
 
@@ -29,8 +30,6 @@ Per-request sampling contract (DESIGN.md §11):
 
     --algorithm NAME            any registered sampler backend (e.g.
                                 ``fused`` = the single-pass kernel, §14)
-    --pool-algorithm NAME       pool-level override: host sampler workers
-                                draw with NAME, the engine keeps --algorithm
     --seed N                    per-request sampling seeds (request i gets
                                 N+i; streams are pure functions of the seed)
     --greedy                    argmax decoding for every request
@@ -83,8 +82,8 @@ def build_engine(arch: str, reduced: bool, algorithm: str, batch: int,
                  prompt_chunk: int = 0, cache: str = "contiguous",
                  block_size: int = 16, num_blocks: int = 0,
                  stages: int = 1, microbatches: int = 0, samplers: int = 2,
-                 sampler_mode: str = None, pool_algorithm: str = None,
-                 telemetry: Telemetry = None, device=None):
+                 sampler_mode: str = None, telemetry: Telemetry = None,
+                 device=None):
     """``device``: where the engine lives (its parameters are made and
     committed there, and the engine follows them); default device if
     None."""
@@ -99,8 +98,7 @@ def build_engine(arch: str, reduced: bool, algorithm: str, batch: int,
                   shvs=SHVSConfig(hot_size=min(1024, cfg.vocab_size // 4)),
                   k_cap=min(256, cfg.vocab_size), seed=seed,
                   cache=cache, block_size=block_size,
-                  num_blocks=num_blocks, samplers=samplers,
-                  pool_algorithm=pool_algorithm)
+                  num_blocks=num_blocks, samplers=samplers)
     if stages > 1 or microbatches:
         if prompt_chunk:
             raise ValueError(
@@ -181,7 +179,6 @@ def build_fleet(args):
                      block_size=args.block_size, num_blocks=args.num_blocks,
                      stages=args.stages, microbatches=args.microbatches,
                      samplers=args.samplers, sampler_mode=args.sampler_mode,
-                     pool_algorithm=args.pool_algorithm,
                      telemetry=_trace_telemetry(args.trace_out),
                      device=devices[i % len(devices)])
         for i in range(n)]
@@ -245,7 +242,6 @@ def run_disaggregated_batch(args) -> None:
             prompt_chunk=args.prompt_chunk, cache=args.cache,
             block_size=args.block_size, num_blocks=args.num_blocks,
             samplers=args.samplers, sampler_mode=args.sampler_mode,
-            pool_algorithm=args.pool_algorithm,
             telemetry=_trace_telemetry(args.trace_out))
 
     stop_sequences = tuple(
@@ -311,8 +307,7 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--samplers", type=int, default=2,
                     help="host sampler pool workers (host sampler mode)")
     ap.add_argument("--sampler-mode",
-                    choices=("device", "host", "disaggregated", "baseline",
-                             "adaptive"),
+                    choices=("device", "host", "adaptive"),
                     default=None,
                     help="decision-plane placement (DESIGN.md §13/§15): "
                          "'device' samples on the accelerator, 'host' "
@@ -321,14 +316,7 @@ def build_parser() -> argparse.ArgumentParser:
                          "'adaptive' lets the DecisionPlaneController "
                          "switch placement and resize the pool online. "
                          "Default: device for the single-stage engine, "
-                         "host for --stages>1. 'disaggregated'/'baseline' "
-                         "are the historic pipeline spellings")
-    ap.add_argument("--pool-algorithm", default=None,
-                    choices=registered_backends(),
-                    help="pool-level backend override (DESIGN.md §14): "
-                         "host-mode sampler workers draw with this backend "
-                         "(e.g. 'fused' for the single-pass kernel) while "
-                         "the engine plane keeps --algorithm")
+                         "host for --stages>1")
     ap.add_argument("--seed", type=int, default=None,
                     help="per-request sampling seeds (request i uses seed+i); "
                          "token streams become pure functions of the seed")
@@ -396,7 +384,6 @@ def main() -> None:
                        stages=args.stages, microbatches=args.microbatches,
                        samplers=args.samplers,
                        sampler_mode=args.sampler_mode,
-                       pool_algorithm=args.pool_algorithm,
                        telemetry=_trace_telemetry(args.trace_out))
     reqs = synth_requests(args.requests, eng.cfg.vocab_size, args.max_new,
                           long_prompts=args.long_prompts, seed=args.seed,

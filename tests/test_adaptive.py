@@ -303,7 +303,7 @@ def test_pipeline_adaptive_bit_identical(model):
 
     got, log = run("adaptive", tweak=force)
     assert any("sampler_mode" in r for r in log), "controller never acted"
-    ref, _ = run("baseline")
+    ref, _ = run("device")
     assert got == ref
 
 

@@ -204,11 +204,11 @@ def test_engine_close_shuts_down_pool(model):
 
 def test_sampler_mode_names():
     assert canonical_sampler_mode("device") == "device"
-    assert canonical_sampler_mode("baseline") == "device"
     assert canonical_sampler_mode("host") == "host"
-    assert canonical_sampler_mode("disaggregated") == "host"
-    with pytest.raises(ValueError, match="sampler_mode"):
-        canonical_sampler_mode("gpu")
+    # the pipeline's old spellings are gone, not aliased
+    for name in ("gpu", "baseline", "disaggregated"):
+        with pytest.raises(ValueError, match="sampler_mode"):
+            canonical_sampler_mode(name)
 
 
 def test_engine_rejects_unknown_sampler_mode(model):
@@ -233,7 +233,7 @@ def test_set_mode_drains_before_reroute(model):
     assert eng.client.set_mode("device") is True
     assert eng.client._tickets == [], "switch left tickets outstanding"
     assert eng.client.mode == "device"
-    assert eng.client.set_mode("disaggregated") is True   # legacy spelling
+    assert eng.client.set_mode("host") is True
     assert eng.client.is_host
     eng.run(max_steps=2000)
     eng.close()

@@ -22,8 +22,9 @@ _SCRIPT = textwrap.dedent("""
     assert len(jax.devices()) == 8
 
     from repro.config import SamplingConfig, SHVSConfig
+    from repro.core import penalties as pen
     from repro.core.decision_plane import DecisionPlane
-    from repro.core.sampling import SamplingParams
+    from repro.core.sampling import SamplingParams, truncation_first_sample
     from repro.models import dist
 
     B, V = 16, 256
@@ -80,18 +81,30 @@ _SCRIPT = textwrap.dedent("""
     B2, V2 = 16, 500   # V not divisible by tp: exercises padding
     z2 = jnp.asarray(np.random.default_rng(3).normal(0, 3, (B2, V2)).astype(np.float32))
     prompts2 = jnp.asarray(np.random.default_rng(4).integers(0, V2, (B2, 4)))
-    for kw, comparator in ((dict(temperature=1.0), "shvs"),
-                           (dict(temperature=0.0), "shvs"),
-                           (dict(temperature=0.9, top_k=20), "truncation_first"),
-                           (dict(temperature=0.8, top_p=0.9), "truncation_first")):
+    def s2_step(dp, z, st, params, step):
+        # filtered rows: the hierarchical plane reproduces the paper's S2
+        # (truncation-first) on the full vocabulary, drawn with column 1
+        core = params.strip_rng()
+        zp = pen.apply_penalties_rows(z, st, core.repetition_penalty,
+                                      core.presence_penalty,
+                                      core.frequency_penalty)
+        u = dp.uniforms(step, z.shape[0])
+        return truncation_first_sample(zp, core, u[:, 1],
+                                       k_cap=dp.k_cap).tokens, st, None
+
+    for kw, s2 in ((dict(temperature=1.0), False),
+                   (dict(temperature=0.0), False),
+                   (dict(temperature=0.9, top_k=20), True),
+                   (dict(temperature=0.8, top_p=0.9), True)):
         params2 = SamplingParams.broadcast(B2, SamplingConfig(
             repetition_penalty=1.2, **kw))
-        dp_ref = DecisionPlane(V2, algorithm=comparator,
+        dp_ref = DecisionPlane(V2, algorithm="shvs",
                                shvs=SHVSConfig(hot_size=64),
                                sampling_parallelism="sequence_parallel",
                                k_cap=64, seed=7)
         st_ref = dp_ref.init_state(B2, prompt_tokens=prompts2)
-        t_ref, _, _ = jax.jit(dp_ref.step)(z2, st_ref, params2, jnp.asarray(0))
+        ref_step = (lambda *a: s2_step(dp_ref, *a)) if s2 else dp_ref.step
+        t_ref, _, _ = jax.jit(ref_step)(z2, st_ref, params2, jnp.asarray(0))
         dp_h = DecisionPlane(V2, algorithm="shvs", shvs=SHVSConfig(hot_size=64),
                              sampling_parallelism="hierarchical", k_cap=64,
                              seed=7)

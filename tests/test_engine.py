@@ -63,15 +63,20 @@ def test_greedy_is_deterministic_across_runs(small_model):
 
 
 def test_greedy_same_for_all_algorithms(small_model):
-    """τ=0 decoding must be algorithm-independent (argmax is argmax)."""
+    """τ=0 decoding must be algorithm-independent (argmax is argmax) —
+    SHVS on a one-token hot set (its full-vocabulary fallback) included."""
     cfg, params = small_model
     results = {}
-    for algo in ("reference", "truncation_first", "shvs"):
-        eng = _engine(cfg, params, algorithm=algo)
+    for name, algo, hot in (("reference", "reference", 64),
+                            ("shvs", "shvs", 64),
+                            ("shvs-fallback", "shvs", 1)):
+        eng = _engine(cfg, params, algorithm=algo,
+                      shvs=SHVSConfig(hot_size=hot))
         eng.submit(_reqs(3, cfg.vocab_size, max_new=5, temperature=0.0))
         done = sorted(eng.run(max_steps=100), key=lambda r: r.request_id)
-        results[algo] = [r.output for r in done]
-    assert results["reference"] == results["truncation_first"] == results["shvs"]
+        results[name] = [r.output for r in done]
+    assert results["reference"] == results["shvs-fallback"] == \
+        results["shvs"]
 
 
 def test_seeded_sampling_deterministic(small_model):

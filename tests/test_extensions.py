@@ -1,56 +1,14 @@
-"""Beyond-paper extensions: Gumbel decision-plane algorithm, online hot-size
-controller (paper future-work (i)), constrained decoding. (The paged KV
-cache suite moved to tests/test_paged_cache.py — DESIGN.md §9.)"""
+"""Beyond-paper extensions: online hot-size controller (paper future-work
+(i)), constrained decoding. (The paged KV cache suite moved to
+tests/test_paged_cache.py — DESIGN.md §9.)"""
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.config import SamplingConfig
+from repro.config import SamplingConfig, SHVSConfig
 from repro.core.autotune import HotSizeController, fit_zipf_s, zipf_alpha_curve
 from repro.core.decision_plane import DecisionPlane
 from repro.core.sampling import SamplingParams, masked_probs_reference
-
-
-class TestGumbelAlgorithm:
-    def test_distribution_exact_no_filter(self):
-        rng = np.random.default_rng(0)
-        B, V = 2, 64
-        z = jnp.asarray(rng.normal(0, 2, (B, V)).astype(np.float32))
-        dp = DecisionPlane(V, algorithm="gumbel", k_cap=32, seed=0)
-        params = SamplingParams.broadcast(B, SamplingConfig(temperature=0.9))
-        target = np.asarray(masked_probs_reference(z, params))
-        N = 5000
-        state = dp.init_state(B)
-        stepped = jax.jit(dp.step)
-        toks = np.stack([np.asarray(stepped(z, state, params, s)[0])
-                         for s in range(N)])
-        for b in range(B):
-            emp = np.bincount(toks[:, b], minlength=V) / N
-            tvd = 0.5 * np.abs(emp - target[b]).sum()
-            assert tvd < 0.05, tvd
-
-    def test_filters_fall_back_to_truncation(self):
-        rng = np.random.default_rng(1)
-        B, V = 8, 64
-        z = jnp.asarray(rng.normal(0, 3, (B, V)).astype(np.float32))
-        dp = DecisionPlane(V, algorithm="gumbel", k_cap=32, seed=0)
-        params = SamplingParams.broadcast(B, SamplingConfig(temperature=0.8,
-                                                            top_k=5))
-        from repro.core.sampling import filter_mask_reference
-        mask = np.asarray(filter_mask_reference(z / 0.8, params))
-        state = dp.init_state(B)
-        for step in range(20):
-            t, state, _ = dp.step(z, state, params, step)
-            assert mask[np.arange(B), np.asarray(t)].all()
-
-    def test_greedy(self):
-        z = jnp.asarray(np.random.default_rng(2).normal(0, 3, (4, 32)),
-                        jnp.float32)
-        dp = DecisionPlane(32, algorithm="gumbel", k_cap=16)
-        params = SamplingParams.broadcast(4, SamplingConfig(temperature=0.0))
-        t, _, _ = dp.step(z, dp.init_state(4), params, 0)
-        np.testing.assert_array_equal(np.asarray(t),
-                                      np.asarray(jnp.argmax(z, -1)))
 
 
 class TestHotSizeController:
@@ -137,8 +95,10 @@ class TestConstrainedDecoding:
         z = jnp.asarray(rng.normal(0, 3, (B, V)).astype(np.float32))
         allow = jnp.asarray(rng.random((B, V)) < 0.3)
         allow = allow.at[:, 0].set(True)      # never-empty support
-        for algo in ("reference", "truncation_first", "shvs", "gumbel"):
-            dp = DecisionPlane(V, algorithm=algo, k_cap=32, seed=3)
+        # hot_size=1: SHVS's full-vocabulary S2 fallback serves the rows
+        for algo, hot in (("reference", 0), ("shvs", 0), ("shvs", 1)):
+            dp = DecisionPlane(V, algorithm=algo, shvs=SHVSConfig(hot_size=hot),
+                               k_cap=32, seed=3)
             params = SamplingParams.broadcast(B, SamplingConfig(
                 temperature=0.9, top_k=10))
             state = dp.init_state(B)

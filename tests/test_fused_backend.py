@@ -1,15 +1,11 @@
-"""Fused-backend service seams (DESIGN.md §14): hot-set re-specialization
-and the pool-level backend override.
+"""Fused-backend service seam (DESIGN.md §14): hot-set re-specialization.
 
 The fused kernel bakes the hot-vocab mask into its traced operands, so the
-SHVS autotuner's ``hot_set`` swap is a stale-operand hazard — the exact
-shape of PR 5's re-jit race, now at the backend layer. These tests pin:
-
-* a plane swapped INTO a hot set is bit-identical to a plane BUILT with
-  it (the ``(algorithm, id(hot_set))`` re-resolve key actually fires);
-* the pool's ``backend_override`` clone picks the swap up through the
-  ordinary ``refresh()`` hook, and is bit-identical to running the fused
-  backend on the device path directly.
+SHVS autotuner's ``hot_set`` swap is a stale-operand hazard — the shape of
+an earlier re-jit race, now at the backend layer. These tests pin that a
+plane swapped INTO a hot set is bit-identical to a plane BUILT with it
+(the ``(algorithm, id(hot_set))`` re-resolve key actually fires). The
+host pool's side of the swap is ``tests/test_decision_backends.py``'s.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -18,7 +14,6 @@ import pytest
 from repro.config import SamplingConfig, SHVSConfig
 from repro.core import penalties as pen
 from repro.core.decision_plane import DecisionPlane
-from repro.core.host_sampler import HostSamplerPool
 from repro.core.hot_vocab import build_hot_set
 from repro.core.sampling import SamplingParams
 from repro.core.shvs import make_hot_set
@@ -90,59 +85,3 @@ class TestHotSwapRespecialization:
         t2, _, s2 = plane.step(logits, state, core, 0)
         np.testing.assert_array_equal(np.asarray(t0), np.asarray(t2))
         assert float(s0.alpha_mean) == float(s2.alpha_mean)
-
-
-class TestPoolBackendOverride:
-    def test_override_matches_device_fused_bitwise(self):
-        """Host workers drawing with ``backend_override="fused"`` must be
-        bit-identical to the fused backend on the direct (full-width)
-        path: uniforms are (request, position)-keyed and the kernel is
-        row-local, so neither the worker sharding nor the clone may move
-        any token."""
-        over = HostSamplerPool(_plane("reference"), num_workers=3,
-                               backend_override="fused")
-        direct = HostSamplerPool(_plane("fused"), num_workers=1)
-        args = _pool_inputs(seed=1)
-        try:
-            got = over.submit(*args).result()
-            want = direct.sample_sync(*args)
-        finally:
-            over.close()
-            direct.close()
-        np.testing.assert_array_equal(got.tokens, want.tokens)
-        np.testing.assert_array_equal(np.asarray(got.state.output_counts),
-                                      np.asarray(want.state.output_counts))
-
-    def test_override_rejects_unknown_backend_at_construction(self):
-        with pytest.raises(ValueError, match="registered backends"):
-            HostSamplerPool(_plane("reference"),
-                            backend_override="not_a_backend")
-
-    def test_refresh_propagates_hot_swap_to_override_clone(self):
-        """The stale-operand regression at the pool seam: after the engine
-        swaps ``plane.hot_set`` and calls ``refresh()``, the override
-        clone must sample against the NEW hot set — bit-identical to a
-        pool built on it — and the worker program must have re-jitted."""
-        plane = _plane("reference")
-        pool = HostSamplerPool(plane, num_workers=2,
-                               backend_override="fused")
-        hot2 = _swapped_hot_set()
-        fresh = HostSamplerPool(_plane("reference", hot_set=hot2),
-                                num_workers=2, backend_override="fused")
-        args = _pool_inputs(seed=2)
-        try:
-            before_jit = pool._step_jit
-            stale = pool.submit(*args).result()
-            plane.hot_set = hot2              # the autotuner's swap ...
-            pool.refresh()                    # ... and the engine's hook
-            assert pool._step_jit is not before_jit, \
-                "refresh() must re-trace the worker program"
-            got = pool.submit(*args).result()
-            want = fresh.submit(*args).result()
-        finally:
-            pool.close()
-            fresh.close()
-        np.testing.assert_array_equal(got.tokens, want.tokens)
-        assert got.alpha_mean == want.alpha_mean
-        assert stale.alpha_mean != got.alpha_mean, \
-            "swap must actually change the measured hot mass"

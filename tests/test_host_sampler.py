@@ -24,10 +24,9 @@ from repro.core.host_sampler import (HostSamplerPool, _pool_stats,
 from repro.core.sampling import SamplingParams
 
 
-def _pool(V=64, workers=2, algorithm="reference", backend_override=None):
+def _pool(V=64, workers=2, algorithm="reference"):
     return HostSamplerPool(DecisionPlane(V, algorithm=algorithm, k_cap=32,
-                                         seed=0), workers,
-                           backend_override=backend_override)
+                                         seed=0), workers)
 
 
 def _inputs(B=8, V=64, active=None, seed=0):
@@ -175,8 +174,7 @@ class TestPlacement:
         from repro.engine.decision_client import DecisionPlaneClient
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         shvs = DecisionPlane(64, algorithm="shvs", k_cap=32, seed=0)
-        with pytest.raises(ValueError, match="Pallas kernel"):
-            DecisionPlaneClient(shvs, "host", pool_algorithm="fused")
+        DecisionPlaneClient(shvs, "host", switchable=True).close()  # jnp: fine
         plane = DecisionPlane(64, algorithm="fused", k_cap=32, seed=0)
         for mode, switchable in (("host", False), ("device", True)):
             with pytest.raises(ValueError, match="Pallas kernel"):

@@ -1,141 +1,32 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps, interpret mode.
+"""The fused Pallas kernel vs its pure-jnp oracle: shape/dtype sweeps,
+interpret mode.
 
-Registry era: beyond the raw kernel-vs-oracle classes, the
+Registry era: beyond the kernel-vs-oracle class, the
 ``TestRegisteredBackendIdentity`` class drives every registered
-:class:`~repro.core.sampler_backend.SamplerBackend` through the
+:class:`~repro.core.sampler_backend.SamplerBackend` (and the
+``shvs-fallback`` variant, ``tests/_variants.py``) through the
 DecisionPlane shell and checks the service-level contracts (greedy
 identity to reference, single-token supports, logit-bias forcing,
 allow-mask restriction, batch-composition invariance). ``REPRO_BACKEND``
 narrows the parametrization to one backend — the CI matrix knob shared
 with ``tests/test_service_api.py``.
 """
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.config import SamplingConfig, SHVSConfig
+from repro.config import SamplingConfig
 from repro.core.decision_plane import DecisionPlane
-from repro.core.sampler_backend import registered_backends
 from repro.core.sampling import SamplingParams
 from repro.kernels import ops, ref
+
+from _variants import assert_fallback, variant_config, variants_under_test
 
 pytestmark = pytest.mark.kernels
 
 SHAPES = [(1, 128), (4, 512), (8, 1024), (3, 700), (16, 2048), (5, 4096)]
 DTYPES = [jnp.float32, jnp.bfloat16]
-
-
-def _backends_under_test():
-    """All registered backends, or just $REPRO_BACKEND (the CI matrix)."""
-    env = os.environ.get("REPRO_BACKEND")
-    if env:
-        assert env in registered_backends(), \
-            f"REPRO_BACKEND={env!r} is not a registered backend"
-        return (env,)
-    return registered_backends()
-
-
-def _inputs(B, V, dtype, seed=0):
-    rng = np.random.default_rng(seed)
-    z = jnp.asarray(rng.normal(0, 4, (B, V)).astype(np.float32)).astype(dtype)
-    cp = jnp.asarray(rng.integers(0, 3, (B, V)), jnp.int32)
-    co = jnp.asarray(rng.integers(0, 3, (B, V)), jnp.int32)
-    rep = jnp.asarray(rng.uniform(1.0, 2.0, B), jnp.float32)
-    pres = jnp.asarray(rng.uniform(0, 1, B), jnp.float32)
-    freq = jnp.asarray(rng.uniform(0, 0.5, B), jnp.float32)
-    temp = jnp.asarray(rng.uniform(0.3, 1.5, B), jnp.float32)
-    return z, cp, co, rep, pres, freq, temp
-
-
-class TestPenaltyKernel:
-    @pytest.mark.parametrize("shape", SHAPES)
-    @pytest.mark.parametrize("dtype", DTYPES)
-    def test_matches_ref(self, shape, dtype):
-        B, V = shape
-        z, cp, co, rep, pres, freq, temp = _inputs(B, V, dtype)
-        out = ops.fused_penalty_scale(z, cp, co, rep, pres, freq, temp)
-        want = ref.penalty_ref(z, cp, co, rep, pres, freq, temp)
-        tol = 1e-5 if dtype == jnp.float32 else 5e-2
-        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                                   rtol=tol, atol=tol)
-
-    def test_noop_penalties_only_scale(self):
-        B, V = 4, 512
-        z, cp, co, *_ = _inputs(B, V, jnp.float32)
-        one = jnp.ones((B,), jnp.float32)
-        zero = jnp.zeros((B,), jnp.float32)
-        temp = jnp.full((B,), 2.0)
-        out = ops.fused_penalty_scale(z, cp * 0, co * 0, one, zero, zero, temp)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(z) / 2.0,
-                                   rtol=1e-5)
-
-
-class TestSHVSKernel:
-    @pytest.mark.parametrize("shape", SHAPES)
-    def test_matches_ref(self, shape):
-        B, V = shape
-        rng = np.random.default_rng(1)
-        z = jnp.asarray(rng.normal(0, 5, (B, V)).astype(np.float32))
-        hot = jnp.asarray(rng.random(V) < 0.25)
-        got = ops.fused_shvs_masses(z, hot)
-        want = ref.shvs_mass_ref(z, hot)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=1e-5, atol=1e-5)
-
-    @pytest.mark.parametrize("block_v", [128, 256, 1024])
-    def test_block_shape_invariance(self, block_v):
-        """Online rescaling must make results independent of tiling."""
-        rng = np.random.default_rng(2)
-        B, V = 4, 2048
-        z = jnp.asarray(rng.normal(0, 8, (B, V)).astype(np.float32))
-        hot = jnp.asarray(rng.random(V) < 0.1)
-        got = ops.fused_shvs_masses(z, hot, block_v=block_v)
-        want = ref.shvs_mass_ref(z, hot)
-        for g, w in zip(got, want):
-            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
-                                       rtol=1e-5, atol=1e-5)
-
-    def test_extreme_logits_stable(self):
-        z = jnp.asarray([[1e4, -1e4, 0.0, 5e3] * 128])
-        hot = jnp.asarray([True, False] * 256)
-        m, s_hot, s_tail, tmax = ops.fused_shvs_masses(z, hot)
-        assert np.isfinite(np.asarray(s_hot)).all()
-        assert np.isfinite(np.asarray(s_tail)).all()
-
-
-class TestGumbelKernel:
-    @pytest.mark.parametrize("shape", SHAPES)
-    def test_bit_identical_to_ref(self, shape):
-        B, V = shape
-        rng = np.random.default_rng(3)
-        z = jnp.asarray(rng.normal(0, 2, (B, V)).astype(np.float32))
-        for seed in (0, 42, 1234):
-            got = ops.fused_gumbel_argmax(z, seed)
-            want = ref.gumbel_argmax_ref(z, seed)
-            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-    def test_distribution_exact(self):
-        """Gumbel-max must sample from softmax(z) exactly."""
-        rng = np.random.default_rng(4)
-        V, N = 32, 8000
-        z = jnp.asarray(rng.normal(0, 2, (1, V)).astype(np.float32))
-        target = np.asarray(jax.nn.softmax(z, -1))[0]
-        toks = np.asarray([int(ref.gumbel_argmax_ref(z, s)[0])
-                           for s in range(N)])
-        emp = np.bincount(toks, minlength=V) / N
-        tvd = 0.5 * np.abs(emp - target).sum()
-        assert tvd < 0.04, tvd
-
-    def test_block_invariance(self):
-        rng = np.random.default_rng(5)
-        z = jnp.asarray(rng.normal(0, 2, (4, 2048)).astype(np.float32))
-        a = ops.fused_gumbel_argmax(z, 7, block_v=256)
-        b = ops.fused_gumbel_argmax(z, 7, block_v=1024)
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ---------------------------------------------------------------------------
@@ -240,9 +131,10 @@ class TestFusedKernel:
 # ---------------------------------------------------------------------------
 
 
-def _plane(algorithm, V=512, seed=0):
-    return DecisionPlane(V, algorithm=algorithm, shvs=SHVSConfig(hot_size=64),
-                         k_cap=64, seed=seed)
+def _plane(variant, V=512, seed=0):
+    algorithm, shvs = variant_config(variant, hot_size=64)
+    return DecisionPlane(V, algorithm=algorithm, shvs=shvs, k_cap=64,
+                         seed=seed)
 
 
 def _plane_inputs(plane, B=6, seed=0):
@@ -256,12 +148,12 @@ def _plane_inputs(plane, B=6, seed=0):
 
 class TestRegisteredBackendIdentity:
     """Plane-level differential identity, parametrized over the registry
-    (the kernel-tier mirror of ``tests/test_service_api.py``'s engine-level
-    suite): on deterministic supports every backend must agree with the
-    ``reference`` backend bit-for-bit, penalties and histogram feedback
-    included."""
+    and ``shvs-fallback`` (the kernel-tier mirror of
+    ``tests/test_service_api.py``'s engine-level suite): on deterministic
+    supports every backend must agree with the ``reference`` backend
+    bit-for-bit, penalties and histogram feedback included."""
 
-    @pytest.mark.parametrize("backend", _backends_under_test())
+    @pytest.mark.parametrize("backend", variants_under_test())
     def test_greedy_multistep_identity_vs_reference(self, backend):
         cfg = SamplingConfig(temperature=0.0, repetition_penalty=1.3,
                              presence_penalty=0.5, frequency_penalty=0.2)
@@ -273,12 +165,14 @@ class TestRegisteredBackendIdentity:
         for step in range(4):
             z = jnp.asarray(rng.normal(0, 3, logits.shape)
                             .astype(np.float32))
-            t_d, state_d, _ = dut.step(z, state_d, params, step)
+            t_d, state_d, stats = dut.step(z, state_d, params, step)
             t_o, state_o, _ = oracle.step(z, state_o, params, step)
             np.testing.assert_array_equal(np.asarray(t_d), np.asarray(t_o),
                                           err_msg=f"step {step}")
+            assert_fallback(backend, float(stats.fallback_rate),
+                            served=False)
 
-    @pytest.mark.parametrize("backend", _backends_under_test())
+    @pytest.mark.parametrize("backend", variants_under_test())
     def test_top_k1_identity_vs_reference(self, backend):
         """top_k=1 at τ>0: a single-token support, so the draw is forced
         and every backend must match reference exactly."""
@@ -288,11 +182,12 @@ class TestRegisteredBackendIdentity:
         logits, state_d = _plane_inputs(dut, seed=2)
         _, state_o = _plane_inputs(oracle, seed=2)
         params = SamplingParams.broadcast(6, cfg).strip_rng()
-        t_d, _, _ = dut.step(logits, state_d, params, 0)
+        t_d, _, stats = dut.step(logits, state_d, params, 0)
         t_o, _, _ = oracle.step(logits, state_o, params, 0)
         np.testing.assert_array_equal(np.asarray(t_d), np.asarray(t_o))
+        assert_fallback(backend, float(stats.fallback_rate), served=True)
 
-    @pytest.mark.parametrize("backend", _backends_under_test())
+    @pytest.mark.parametrize("backend", variants_under_test())
     def test_logit_bias_forces_token(self, backend):
         plane = _plane(backend)
         B, V = 6, plane.vocab_size
@@ -302,11 +197,12 @@ class TestRegisteredBackendIdentity:
         bias[np.arange(B), forced] = 1e9
         params = SamplingParams.broadcast(
             B, SamplingConfig(temperature=1.0, top_k=4)).strip_rng()
-        toks, _, _ = plane.step(logits, state, params, 0,
-                                logit_bias=jnp.asarray(bias))
+        toks, _, stats = plane.step(logits, state, params, 0,
+                                    logit_bias=jnp.asarray(bias))
         np.testing.assert_array_equal(np.asarray(toks), forced)
+        assert_fallback(backend, float(stats.fallback_rate), served=True)
 
-    @pytest.mark.parametrize("backend", _backends_under_test())
+    @pytest.mark.parametrize("backend", variants_under_test())
     def test_allow_mask_restricts_support(self, backend):
         plane = _plane(backend)
         B, V = 6, plane.vocab_size
@@ -317,16 +213,15 @@ class TestRegisteredBackendIdentity:
             allow[b, rng.choice(V, 8, replace=False)] = True
         params = SamplingParams.broadcast(
             B, SamplingConfig(temperature=1.0)).strip_rng()
-        toks = np.asarray(plane.step(logits, state, params, 0,
-                                     allow_mask=jnp.asarray(allow))[0])
-        assert allow[np.arange(B), toks].all()
+        toks, _, stats = plane.step(logits, state, params, 0,
+                                    allow_mask=jnp.asarray(allow))
+        assert allow[np.arange(B), np.asarray(toks)].all()
+        assert_fallback(backend, float(stats.fallback_rate), served=False)
 
-    @pytest.mark.parametrize("backend", _backends_under_test())
+    @pytest.mark.parametrize("backend", variants_under_test())
     def test_batch_composition_invariance(self, backend):
         """With (request, position)-keyed uniforms, a row's token cannot
-        depend on which other rows share the batch. Filtered config: the
-        gumbel backend's unfiltered fast path is deliberately keyed on the
-        local row index and documented shard-variant."""
+        depend on which other rows share the batch."""
         cfg = SamplingConfig(temperature=0.9, top_k=8)
         B = 6
         plane = _plane(backend)
@@ -334,9 +229,11 @@ class TestRegisteredBackendIdentity:
         params = SamplingParams.broadcast(B, cfg)
         nonces = np.arange(100, 100 + B, dtype=np.uint32)
         pos = np.full((B,), 9, np.int32)
-        full = np.asarray(plane.step(
+        full, _, stats = plane.step(
             logits, state, params, 0,
-            rng_tags=(jnp.asarray(nonces), jnp.asarray(pos)))[0])
+            rng_tags=(jnp.asarray(nonces), jnp.asarray(pos)))
+        full = np.asarray(full)
+        assert_fallback(backend, float(stats.fallback_rate), served=True)
 
         keep = np.asarray([1, 3, 4])
         sub_plane = _plane(backend)

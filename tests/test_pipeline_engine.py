@@ -115,7 +115,7 @@ def test_baseline_sampler_mode_identical(model4, reference, cache):
     the schedule (and the bubble) differs."""
     cfg, params = model4
     got, _ = _pipeline(cfg, params, _reqs(cfg), stages=2, microbatches=4,
-                       sampler_mode="baseline", cache=cache)
+                       sampler_mode="device", cache=cache)
     assert got == reference
 
 
@@ -261,8 +261,8 @@ def test_measured_bubble_disaggregated_below_baseline(model4):
     vocab-heavy decision plane (full-V reference backend) makes the
     sampling epilogue material, as in the paper's Fig. 1b."""
     from benchmarks.fig_pipeline import measure
-    base = measure(stages=2, microbatches=4, mode="baseline")
-    simple = measure(stages=2, microbatches=4, mode="disaggregated")
+    base = measure(stages=2, microbatches=4, mode="device")
+    simple = measure(stages=2, microbatches=4, mode="host")
     assert base["cycles"] > 0 and simple["cycles"] > 0
     assert simple["bubble_frac"] < base["bubble_frac"], (
         f"disaggregated bubble {simple['bubble_frac']:.3f} not below "

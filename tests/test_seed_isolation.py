@@ -38,8 +38,8 @@ from repro.engine.engine import EngineConfig
 from repro.models.model import Model
 
 # runs under the CI backend matrix too: the isolation contract holds for
-# every backend whose stochastic draws consume the tagged uniforms (all of
-# them — gumbel's filtered path included, and the target config is filtered)
+# every registered backend, since each one's stochastic draws consume the
+# tagged uniforms
 ALGORITHM = os.environ.get("REPRO_BACKEND", "shvs")
 
 TARGET_CFG = SamplingConfig(temperature=0.9, top_k=12, top_p=0.95,

@@ -5,18 +5,19 @@ Four contract surfaces:
 * the **backend registry** — selection by name, loud ``ValueError`` on
   unknown names, at construction and at step time;
 * the **backend-differential identity suite** (``backends`` marker; CI
-  runs it once per registered backend via ``REPRO_BACKEND``) — backends
-  are bit-identical to the reference sampler on shared configs (greedy /
-  single-token supports) across {overlapped, sequential} × {contiguous,
-  paged}, bit-identical to themselves across modes on seeded stochastic
-  configs, and seeded streams are invariant to batch composition;
+  runs it once per registered backend via ``REPRO_BACKEND``, the
+  ``shvs-fallback`` variant under ``shvs``) — backends are bit-identical
+  to the reference sampler on shared configs (greedy / single-token
+  supports) across {overlapped, sequential} × {contiguous, paged}, with
+  the decision on the device and, for ``shvs`` and ``fused``, in the host
+  sampler pool; bit-identical to themselves across modes on seeded
+  stochastic configs; and seeded streams are invariant to batch
+  composition;
 * the **per-request contract** — seed / greedy / logit_bias /
   stop_sequences / finish_reason;
 * the **streaming surface** — ``Engine.generate()`` events fire at commit,
   incrementally, and collect to exactly the ``submit``+``run`` streams.
 """
-import os
-
 import jax
 import numpy as np
 import pytest
@@ -30,18 +31,10 @@ from repro.engine import Engine, GenerationEvent, Request, SlotParams
 from repro.engine.engine import EngineConfig
 from repro.models.model import Model
 
-BUILTIN_BACKENDS = ("fused", "gumbel", "reference", "shvs",
-                    "truncation_first")
+from _variants import (SHVS_FALLBACK, backends_under_test, variant_config,
+                       variants_under_test)
 
-
-def _backends_under_test():
-    """All registered backends, or just $REPRO_BACKEND (the CI matrix)."""
-    env = os.environ.get("REPRO_BACKEND")
-    if env:
-        assert env in registered_backends(), \
-            f"REPRO_BACKEND={env!r} is not a registered backend"
-        return (env,)
-    return registered_backends()
+BUILTIN_BACKENDS = ("fused", "reference", "shvs")
 
 
 @pytest.fixture(scope="module")
@@ -76,9 +69,7 @@ def _copy(reqs):
 
 class TestRegistry:
     def test_builtins_registered(self):
-        names = registered_backends()
-        for b in BUILTIN_BACKENDS:
-            assert b in names
+        assert registered_backends() == BUILTIN_BACKENDS
 
     def test_make_backend_unknown_lists_registered(self):
         with pytest.raises(ValueError) as ei:
@@ -145,9 +136,8 @@ def _shared_reqs(cfg):
 
 
 def _seeded_reqs(cfg):
-    """Seeded stochastic filtered configs: every backend (gumbel included —
-    its filtered path consumes the tagged uniforms) must reproduce its own
-    stream bit-for-bit across engine modes."""
+    """Seeded stochastic filtered configs: every backend must reproduce its
+    own stream bit-for-bit across engine modes."""
     rng = np.random.default_rng(23)
     return [Request(
         i, rng.integers(1, cfg.vocab_size, int(rng.integers(3, 8))).tolist(),
@@ -156,36 +146,58 @@ def _seeded_reqs(cfg):
         for i in range(4)]
 
 
+def _variant_engine(cfg, params, variant, **kw):
+    algorithm, shvs = variant_config(variant, hot_size=64)
+    return _engine(cfg, params, algorithm=algorithm, shvs=shvs, **kw)
+
+
 def _streams(cfg, params, cache_dict, backend, overlap, kv, workload,
-             reqs_fn):
-    key = (backend, overlap, kv, workload)
+             reqs_fn, sampler_mode="device"):
+    key = (backend, overlap, kv, workload, sampler_mode)
     if key not in cache_dict:
-        eng = _engine(cfg, params, algorithm=backend, overlap=overlap,
-                      cache=kv)
+        eng = _variant_engine(cfg, params, backend, overlap=overlap,
+                              cache=kv, sampler_mode=sampler_mode)
         reqs = reqs_fn(cfg)
         eng.submit(reqs)
         done = eng.run(max_steps=400)
+        eng.close()
         assert len(done) == len(reqs)
+        if backend == SHVS_FALLBACK:
+            # both workloads sample filtered rows, which the one-token hot
+            # set cannot hold: the S2 fallback must really have served
+            assert max(r.fallback_rate for r in eng.stats_log) > 0.0, \
+                "no row took the full-vocabulary fallback"
         cache_dict[key] = {r.request_id: list(r.output) for r in done}
     return cache_dict[key]
+
+
+def _placements():
+    """Every variant with the decision on the device, plus ``shvs`` and
+    ``fused`` in the host sampler pool."""
+    out = [pytest.param(v, "device", id=v) for v in variants_under_test()]
+    out += [pytest.param(b, "host", id=f"{b}-host")
+            for b in backends_under_test() if b in ("shvs", "fused")]
+    return out
 
 
 @pytest.mark.backends
 class TestBackendDifferential:
     @pytest.mark.parametrize("overlap,kv", MODES)
-    @pytest.mark.parametrize("backend", _backends_under_test())
+    @pytest.mark.parametrize("backend,sampler_mode", _placements())
     def test_bit_identical_to_reference_on_shared_configs(
-            self, small_model, stream_cache, backend, overlap, kv):
+            self, small_model, stream_cache, backend, sampler_mode, overlap,
+            kv):
         cfg, params = small_model
         ref = _streams(cfg, params, stream_cache, "reference", False,
                        "contiguous", "shared", _shared_reqs)
         got = _streams(cfg, params, stream_cache, backend, overlap, kv,
-                       "shared", _shared_reqs)
+                       "shared", _shared_reqs, sampler_mode)
         assert got == ref, (
-            f"{backend} [{'overlap' if overlap else 'seq'}, {kv}] diverged "
+            f"{backend} [{sampler_mode}, "
+            f"{'overlap' if overlap else 'seq'}, {kv}] diverged "
             f"from the reference sampler on shared configs")
 
-    @pytest.mark.parametrize("backend", _backends_under_test())
+    @pytest.mark.parametrize("backend", variants_under_test())
     def test_cross_mode_identity_on_seeded_stochastic_configs(
             self, small_model, stream_cache, backend):
         cfg, params = small_model
@@ -195,7 +207,7 @@ class TestBackendDifferential:
                      "seeded", _seeded_reqs)
         assert a == b, f"{backend}: overlap+contiguous != sequential+paged"
 
-    @pytest.mark.parametrize("backend", _backends_under_test())
+    @pytest.mark.parametrize("backend", variants_under_test())
     def test_seeded_stream_invariant_to_batch_composition(
             self, small_model, backend):
         """Same per-request seed, different co-resident requests, different
@@ -219,7 +231,7 @@ class TestBackendDifferential:
                       for j in range(2 if order == "last" else 3)]
             batch = others + [target] if order == "last" \
                 else [target] + others
-            eng = _engine(cfg, params, algorithm=backend)
+            eng = _variant_engine(cfg, params, backend)
             eng.submit(batch)
             eng.run(max_steps=400)
             assert target.done

@@ -83,7 +83,9 @@ def test_decision_plane_is_separate_program(model_and_logits):
 def test_histograms_track_served_tokens(model_and_logits):
     cfg, logits, toks = model_and_logits
     B = logits.shape[0]
-    dp = DecisionPlane(cfg.vocab_size, algorithm="truncation_first", k_cap=64)
+    # a one-token hot set: SHVS's full-vocabulary fallback serves the rows
+    dp = DecisionPlane(cfg.vocab_size, algorithm="shvs",
+                       shvs=SHVSConfig(hot_size=1), k_cap=64)
     params = SamplingParams.broadcast(B, SamplingConfig(temperature=0.7,
                                                         top_k=20))
     state = dp.init_state(B)

@@ -1,7 +1,7 @@
-"""Compile the serving path's Pallas kernels for a TPU v5e without a chip.
+"""Compile the fused Pallas kernel for a TPU v5e without a chip.
 
 The TPU compiler ships with jaxlib, so a described ``v5e:2x2`` topology is
-enough to lower and compile each kernel with ``interpret=False`` — the
+enough to lower and compile the kernel with ``interpret=False`` — the
 check that Mosaic accepts the kernel at real vocabulary sizes, which an
 interpret-mode test on the CPU cannot make. The topology is described in a
 module fixture (never at import), and the tests skip where it cannot be.
@@ -15,7 +15,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.sampling import SamplingParams
-from repro.kernels import fused_kernel, ops, penalty_kernel
+from repro.kernels import fused_kernel, ops
 
 
 @pytest.fixture(scope="module")
@@ -76,15 +76,4 @@ def test_ops_wrapper_compiles_the_kernel_when_lowered_for_tpu(one_chip):
 
     compiled = jax.jit(call).lower(
         *_fused_operands(one_chip, 16, 49152)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_penalty_kernel_compiles_for_v5e(one_chip):
-    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    B, V = 8, 49152
-    args = ([s((B, V), jnp.float32), s((B, V), jnp.int32),
-             s((B, V), jnp.int32)] + [s((B,), jnp.float32)] * 4)
-    fn = jax.jit(lambda *a: penalty_kernel.penalty_scale(
-        *a, block_b=8, block_v=2048, interpret=False))
-    compiled = fn.lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
